@@ -680,7 +680,8 @@ print("WARM_JSON=" + json.dumps({{
 
 
 def load_stage_breakdown() -> dict:
-    """The load.* stage seconds (verify / read / assemble / h2d) plus
+    """The load.* stage seconds (verify / read / assemble / layout /
+    cache_write / h2d) plus
     effective H2D bandwidth from this process's telemetry registry —
     recorded in every BENCH row and BENCH_HISTORY.jsonl so the
     cold-start trajectory is tracked like throughput (ISSUE 5). Stages

@@ -420,9 +420,13 @@ def test_request_span_tree_and_level_histogram(scorer):
     assert res.level == "full"
     req = [t for t in obs.recent_traces() if t.name == "request"][-1]
     child_names = [c.name for c in req.children]
-    assert child_names[:3] == ["ladder", "admission_wait", "breaker"]
-    assert "dispatch" in child_names
-    disp = req.children[child_names.index("dispatch")]
+    assert child_names[:4] == ["ladder", "admission_wait", "breaker",
+                               "search"]
+    # the scorer's batch span: analysis, then the dispatch it schedules
+    search_names = [c.name for c in req.children[3].children]
+    assert search_names[0] == "search.analyze"
+    assert "dispatch" in search_names
+    disp = req.children[3].children[search_names.index("dispatch")]
     assert any(c.name == "kernel" for c in disp.children)
     assert req.attrs["level"] == "full"
     reg = obs.get_registry()

@@ -185,8 +185,9 @@ def test_slow_query_trap_end_to_end(scorer, tmp_path, monkeypatch):
     assert cap["slow"] is True
     # span tree: the frontend's still-open request root
     assert cap["span_tree"]["name"] == "request"
-    assert any(c["name"] == "dispatch"
-               for c in cap["span_tree"]["children"])
+    search = next(c for c in cap["span_tree"]["children"]
+                  if c["name"] == "search")
+    assert any(c["name"] == "dispatch" for c in search["children"])
     # explain: bit-exact decomposition of the top hit
     ex = cap["explain"][0]
     assert ex["contribution_sum"] == ex["score"] == res[0][1]
@@ -239,13 +240,34 @@ def test_slow_trap_explain_rides_the_rate_gate(scorer, tmp_path,
 
 def test_slow_capture_without_frontend_uses_ring_span(scorer, tmp_path,
                                                       monkeypatch):
+    """With no span open on the recording thread, the capture takes the
+    newest closed root from the ring (here a bare topk's dispatch)."""
+    monkeypatch.setenv("TPU_IR_FLIGHT_DIR", str(tmp_path))
+    querylog.configure(slow_ms=0.0001)
+    obs.reset_rate_limit()
+    scorer.topk(scorer.analyze_queries(["salmon fishing"]), k=3,
+                scoring="bm25")
+    querylog.record({"total_ms": 1.0})
+    cap = querylog.slow_recent()[-1]
+    assert cap.get("span_tree") is not None
+    assert cap.get("span_tree_source") == "ring"
+    assert cap["span_tree"]["name"] == "dispatch"
+
+
+def test_slow_capture_of_plain_search_batch_uses_its_search_span(
+        scorer, tmp_path, monkeypatch):
+    """A plain Scorer.search_batch records its query log inside its own
+    `search` span, so a slow capture takes that live tree."""
     monkeypatch.setenv("TPU_IR_FLIGHT_DIR", str(tmp_path))
     querylog.configure(slow_ms=0.0001)
     obs.reset_rate_limit()
     scorer.search_batch(["salmon fishing"], k=3, scoring="bm25")
     cap = querylog.slow_recent()[-1]
-    assert cap.get("span_tree") is not None
-    assert cap.get("span_tree_source") == "ring"
+    assert "span_tree_source" not in cap
+    tree = cap["span_tree"]
+    assert tree["name"] == "search"
+    assert [c["name"] for c in tree["children"]][0] == "search.analyze"
+    assert any(c["name"] == "dispatch" for c in tree["children"])
 
 
 # ---------------------------------------------------------------------------

@@ -76,6 +76,7 @@ import shutil
 import time
 from typing import Iterable, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -854,24 +855,37 @@ def _build_index_streaming(
         # runs on a prefetch thread, so disk reads + temp-id remaps for
         # item N+1 overlap the device reduce of item N AND the D2H
         # collect of item N-1 — the double-buffered pipeline.
+        #
+        # Its parts are report phases nested inside pass2_combine (which
+        # keeps timing the whole): pass2_upload (padding + uploads),
+        # pass2_device_wait (the waits on device programs: this item's
+        # df, then its shrink — queued behind the NEXT item's group-by,
+        # dispatched before this collect), pass2_fetch (the pair copy to
+        # the host) and pass2_spill (term ids, per-shard split, spill
+        # writes).
         use16 = v < int(PAD_TERM_U16)
         buckets = unit == "buckets"
 
         def collect_batch(b, p, tf_max, t0):
-            df_b, tfm = fetch_to_host(p.df, tf_max)
-            npairs = int(df_b.sum())
-            pd, ptf = fetch_to_host(*shrink_pairs(
-                p.pair_doc, p.pair_tf, npairs, num_docs=num_docs,
-                tf_max=int(tfm)))
-            pt = pair_term_from_df(df_b)
-            pd = pd[:npairs]
-            ptf = ptf[:npairs]
-            shard = pt % num_shards
-            for s in range(num_shards):
-                sel = shard == s
-                fmt.savez_atomic(
-                    os.path.join(spill_dir, f"pairs-{s:03d}-{b:05d}.npz"),
-                    term=pt[sel], doc=pd[sel], tf=ptf[sel])
+            with report.phase("pass2_device_wait"):
+                df_b, tfm = fetch_to_host(p.df, tf_max)
+                npairs = int(df_b.sum())
+                shrunk = jax.block_until_ready(shrink_pairs(
+                    p.pair_doc, p.pair_tf, npairs, num_docs=num_docs,
+                    tf_max=int(tfm)))
+            with report.phase("pass2_fetch"):
+                pd, ptf = fetch_to_host(*shrunk)
+            with report.phase("pass2_spill"):
+                pt = pair_term_from_df(df_b)
+                pd = pd[:npairs]
+                ptf = ptf[:npairs]
+                shard = pt % num_shards
+                for s in range(num_shards):
+                    sel = shard == s
+                    fmt.savez_atomic(
+                        os.path.join(spill_dir,
+                                     f"pairs-{s:03d}-{b:05d}.npz"),
+                        term=pt[sel], doc=pd[sel], tf=ptf[sel])
             report_progress("pass2_combine", advance=1,
                             spills_written=num_shards, pairs=npairs)
             if buckets:
@@ -886,22 +900,24 @@ def _build_index_streaming(
         pending = None
         for b, term_ids, docnos, lengths in batch_iter:
             t0 = time.perf_counter()
-            cap = _round_cap(len(term_ids))
-            t_pad = np.full(cap, PAD_TERM_U16 if use16 else PAD_TERM,
-                            np.uint16 if use16 else np.int32)
-            t_pad[: len(term_ids)] = term_ids
-            # docnos/lengths are padded to a bucketed doc capacity
-            # (zero-length repeats are no-ops) so batches of similar size
-            # share one compiled program shape; batches can overshoot
-            # batch_docs by up to one tokenizer chunk
-            doc_cap = _round_cap(len(lengths), 1 << 14)
-            d_pad = np.zeros(doc_cap, np.int32)
-            l_pad = np.zeros(doc_cap, np.int32)
-            d_pad[: len(docnos)] = docnos
-            l_pad[: len(docnos)] = lengths
-            p = build_postings_packed_jit(
-                jnp.asarray(t_pad), jnp.asarray(d_pad), jnp.asarray(l_pad),
-                vocab_size=v, num_docs=num_docs)
+            with report.phase("pass2_upload"):
+                cap = _round_cap(len(term_ids))
+                t_pad = np.full(cap, PAD_TERM_U16 if use16 else PAD_TERM,
+                                np.uint16 if use16 else np.int32)
+                t_pad[: len(term_ids)] = term_ids
+                # docnos/lengths are padded to a bucketed doc capacity
+                # (zero-length repeats are no-ops) so batches of similar
+                # size share one compiled program shape; batches can
+                # overshoot batch_docs by up to one tokenizer chunk
+                doc_cap = _round_cap(len(lengths), 1 << 14)
+                d_pad = np.zeros(doc_cap, np.int32)
+                l_pad = np.zeros(doc_cap, np.int32)
+                d_pad[: len(docnos)] = docnos
+                l_pad[: len(docnos)] = lengths
+                args = (jnp.asarray(t_pad), jnp.asarray(d_pad),
+                        jnp.asarray(l_pad))
+            p = build_postings_packed_jit(*args, vocab_size=v,
+                                          num_docs=num_docs)
             tf_max = jnp.max(p.pair_tf)
             for a in (p.df, tf_max):
                 a.copy_to_host_async()
@@ -1176,16 +1192,18 @@ def _build_index_streaming(
         with report.phase("chargrams"):
             build_chargram_artifacts(index_dir, vocab.terms, chargram_ks)
 
-    if not keep_spills:
-        shutil.rmtree(spill_dir, ignore_errors=True)
-
-    meta = fmt.IndexMetadata(
-        num_docs=num_docs, vocab_size=v, k=k, num_shards=num_shards,
-        num_pairs=num_pairs_total,
-        chargram_ks=chargram_ks if built_chargrams else [],
-        version=2 if positions else fmt.FORMAT_VERSION,
-        has_positions=bool(positions),
-        format_version=fmt.resolve_format_version())
-    meta.save_with_checksums(index_dir)
+    # spill removal, then metadata: its checksums over every artifact
+    # and the block-max bounds artifact it writes
+    with report.phase("finalize"):
+        if not keep_spills:
+            shutil.rmtree(spill_dir, ignore_errors=True)
+        meta = fmt.IndexMetadata(
+            num_docs=num_docs, vocab_size=v, k=k, num_shards=num_shards,
+            num_pairs=num_pairs_total,
+            chargram_ks=chargram_ks if built_chargrams else [],
+            version=2 if positions else fmt.FORMAT_VERSION,
+            has_positions=bool(positions),
+            format_version=fmt.resolve_format_version())
+        meta.save_with_checksums(index_dir)
     report.save(os.path.join(index_dir, fmt.JOBS_DIR))
     return meta

@@ -48,16 +48,17 @@ from .registry import (
     SERVICE_LEVELS,
     SNAPSHOT_SCHEMA,
     TelemetryRegistry,
+    capture_active,
     get_registry,
 )
 from .trace import (
     Span,
     attach,
+    capture_totals,
     clear_traces,
     configure,
     current_span,
     enabled,
-    kernel_annotation,
     recent_traces,
     record_span,
     trace,
@@ -101,8 +102,8 @@ __all__ = [
     "GAUGE_MERGE",
     "progress", "start_job", "report_progress", "current_job",
     "Span", "trace", "attach", "current_span", "recent_traces",
-    "clear_traces", "configure", "enabled", "kernel_annotation",
-    "record_span", "reset_all",
+    "clear_traces", "configure", "enabled", "capture_active",
+    "capture_totals", "record_span", "reset_all",
     "profiling", "profiled_jit", "profile_report", "sample_memory",
     "recompiles_last_60s",
     "querylog",

@@ -485,9 +485,10 @@ def profile_report() -> dict:
     """THE profiling view (`tpu-ir profile`, GET /profile): per-function
     per-signature compile counts with wall time and cost_analysis
     FLOPs/bytes, the dispatch time split (trace/compile/device) and
-    compile.time histograms, the memory gauges, and the recompile
-    window. Per-process, like `tpu-ir stats` — meaningful from a
-    serving or bench process, empty from a fresh CLI."""
+    compile.time histograms, the memory gauges, the recompile window,
+    and the newest profiler capture's totals. Per-process, like `tpu-ir
+    stats` — meaningful from a serving or bench process, empty from a
+    fresh CLI."""
     reg = get_registry()
     snap = reg.snapshot()
     hists = snap.get("histograms", {})
@@ -551,6 +552,9 @@ def profile_report() -> dict:
         # and every live cache's control-plane snapshot — read next to
         # the dispatch split to see what each hit SKIPPED paying
         "cache": _cache_section(snap, hists),
+        # the program's own totals for the newest profiler capture
+        # (registry.capture_totals), read next to its xplane
+        "capture": reg.capture_totals(),
         "gauges": snap.get("gauges", {}),
         "memory": memory_snapshot(),
     }

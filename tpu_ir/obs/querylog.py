@@ -43,7 +43,7 @@ import time
 import zlib
 
 from ..utils import envvars
-from .trace import current_root, recent_traces
+from .trace import current_tree, recent_traces
 from .recorder import flight_dump
 from .registry import get_registry
 
@@ -214,15 +214,15 @@ def _capture_slow(entry: dict, explain_fn) -> None:
     t0 = time.perf_counter()
     capture = dict(entry)
     try:
-        root = current_root()
-        if root is not None:
-            # the still-open request tree (the ServingFrontend path:
-            # its "request" root is live on this thread)
-            capture["span_tree"] = root.to_dict()
+        tree = current_tree()
+        if tree is not None:
+            # the still-open tree: the ServingFrontend's "request"
+            # root, or a plain Scorer.search_batch's "search" span
+            capture["span_tree"] = tree
         else:
-            # plain Scorer calls record after their dispatch root spans
-            # closed into the ring — take the newest (best-effort: under
-            # concurrency it can belong to a neighboring request)
+            # a record made outside any span: take the newest closed
+            # root from the ring (best-effort: under concurrency it can
+            # belong to a neighboring request)
             recent = recent_traces()
             if recent:
                 capture["span_tree"] = recent[-1].to_dict()

@@ -17,10 +17,16 @@ histogram for every serving stage and service level, so a failure path
 or ladder level with NO telemetry is structurally impossible —
 tests/test_obs.py introspects the source for injection sites and the
 frontend for levels and asserts both land in the declared sets.
+
+Capture ledger: while a jax profiler capture records, every increment
+and observation also adds to per-capture totals (`capture_totals()`),
+so the program's own counts and span seconds for the window an xplane
+covers can be read next to it.
 """
 
 from __future__ import annotations
 
+import sys
 import threading
 import uuid
 
@@ -80,8 +86,19 @@ SERVICE_LEVELS = ("full", "no_rerank", "hot_only", "shed")
 # shard reads, CSR assembly, host-to-device streaming. Declared so
 # `tpu-ir metrics` and the bench's load breakdown always report the full
 # stage set, observed or not; load.h2d pairs with the load.h2d_bytes
-# counter for an effective-MB/s readout.
-LOAD_STAGES = ("load.verify", "load.read", "load.assemble", "load.h2d")
+# counter for an effective-MB/s readout. load.verify nests inside
+# load.read (the CRC folds into the streamed read); load.layout (block
+# bounds + tiered layout build on the host, the hot strip's scatter on
+# the device) and load.cache_write (rerank norms + serving-cache write,
+# cache-miss only) complete the cold load, and `load` spans the whole
+# Scorer.load.
+LOAD_STAGES = ("load.verify", "load.read", "load.assemble", "load.layout",
+               "load.cache_write", "load.h2d")
+
+# Scorer query-path spans, one per batch: the whole plain-query search
+# (analysis, schedule, dispatch, result assembly, query log), its query
+# analysis, and the MaxScore hot/cold partition.
+SEARCH_STAGES = ("search", "search.analyze", "search.schedule")
 
 # Recovery-event counter names (the `recovery.` namespace, incremented
 # via utils/report.recovery_counters()). Declared so the lint contract
@@ -296,7 +313,8 @@ DECLARED_COUNTERS = tuple(f"fault.{s}" for s in FAULT_SITES) + (
      + COMPRESS_COUNTER_NAMES)
 # "request" (the root span, all levels pooled) rides alongside the
 # per-level request.<level> histograms — same observations, two cuts
-DECLARED_HISTOGRAMS = ("request",) + REQUEST_STAGES + LOAD_STAGES + tuple(
+DECLARED_HISTOGRAMS = ("request", "load") + REQUEST_STAGES + LOAD_STAGES \
+    + SEARCH_STAGES + tuple(
     f"request.{lv}" for lv in SERVICE_LEVELS) + DISPATCH_STAGES + (
     # wall time per compile event (trace + backend compile)
     "compile.time",
@@ -411,6 +429,29 @@ def _prom_name(name: str) -> str:
     return name.replace(".", "_").replace("-", "_")
 
 
+# jax.profiler.TraceAnnotation (jaxlib's TraceMe), found once jax has
+# been imported by someone else: obs never imports jax itself
+_TRACE_ME = None
+
+
+def trace_me():
+    """The TraceMe class of the jax already imported, or None."""
+    global _TRACE_ME
+    if _TRACE_ME is None:
+        prof = getattr(sys.modules.get("jax"), "profiler", None)
+        _TRACE_ME = getattr(prof, "TraceAnnotation", None)
+    return _TRACE_ME
+
+
+def capture_active() -> bool:
+    """Whether a profiler capture (jax.profiler.start_trace, `--profile
+    DIR`, or a capture through the profiler server) is recording now:
+    one TraceMe.is_enabled() call, and False while jax is not
+    imported."""
+    tm = _TRACE_ME or trace_me()
+    return tm is not None and tm.is_enabled()
+
+
 class TelemetryRegistry:
     """Process-wide counters + latency histograms, one snapshot/reset
     API. All methods are thread-safe; the hot-path cost of an increment
@@ -439,6 +480,13 @@ class TelemetryRegistry:
         self._seq = 0
         self._resets = 0
         self.run_id = uuid.uuid4().hex
+        # the capture ledger: totals of what was observed while a
+        # profiler capture recorded (capture_totals). _cap_on is the
+        # verdict of the last capture check; the first check that finds
+        # a capture after one that found none starts a fresh ledger.
+        self._cap_on = False
+        self._cap_counters: dict[str, int] = {}
+        self._cap_hists: dict[str, list] = {}
 
     @property
     def seq(self) -> int:
@@ -451,8 +499,12 @@ class TelemetryRegistry:
     # -- counters ----------------------------------------------------------
 
     def incr(self, name: str, amount: int = 1) -> None:
+        captured = self.capture_check()
         with self._lock:
             self._counters[name] = self._counters.get(name, 0) + amount
+            if captured:
+                self._cap_counters[name] = (self._cap_counters.get(name, 0)
+                                            + amount)
 
     def get(self, name: str) -> int:
         with self._lock:
@@ -521,8 +573,49 @@ class TelemetryRegistry:
                 h = self._hists.setdefault(name, LatencyHistogram())
         return h
 
-    def observe(self, name: str, seconds: float) -> None:
+    def observe(self, name: str, seconds: float,
+                captured: bool | None = None) -> None:
+        """One observation. `captured` is the verdict of a capture check
+        the caller already made (a span checks once, when it opens);
+        None checks here."""
         self.histogram(name).observe(seconds)
+        if captured or (captured is None and self.capture_check()):
+            with self._lock:
+                tot = self._cap_hists.setdefault(name, [0, 0.0])
+                tot[0] += 1
+                tot[1] += seconds
+
+    # -- the capture ledger ------------------------------------------------
+
+    def capture_check(self) -> bool:
+        """Whether a profiler capture is recording (capture_active). The
+        first check that finds one after a check that found none clears
+        the capture ledger, so two captures with no telemetry between
+        them share one ledger."""
+        tm = _TRACE_ME or trace_me()  # capture_active(), inlined
+        if tm is None or not tm.is_enabled():
+            if self._cap_on:
+                self._cap_on = False
+            return False
+        if not self._cap_on:
+            with self._lock:
+                if not self._cap_on:
+                    self._cap_counters.clear()
+                    self._cap_hists.clear()
+                    self._cap_on = True
+        return True
+
+    def capture_totals(self) -> dict:
+        """What the newest profiler capture observed: {"capturing": is
+        one recording now, "counters": {name: total}, "histograms":
+        {name: {"count": n, "sum_s": seconds}}} — the program's own
+        totals for the window an xplane covers."""
+        capturing = capture_active()
+        with self._lock:
+            return {"capturing": capturing,
+                    "counters": dict(self._cap_counters),
+                    "histograms": {n: {"count": c, "sum_s": s}
+                                   for n, (c, s) in self._cap_hists.items()}}
 
     def histogram_names(self) -> tuple:
         with self._lock:
@@ -636,6 +729,8 @@ class TelemetryRegistry:
                 else:
                     del self._gauges[k]
             self._gauges_set.clear()
+            self._cap_counters.clear()
+            self._cap_hists.clear()
             self._seq += 1
             self._resets += 1
             hists = dict(self._hists)

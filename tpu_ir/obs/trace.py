@@ -28,6 +28,16 @@ Cross-thread spans: faults.run_with_deadline re-parents its worker
 thread onto the caller's current span via `attach()`, so a deadlined
 dispatch's kernel spans stay inside the request tree instead of
 surfacing as orphan roots.
+
+Profiler captures: while a jax.profiler capture records (`--profile
+DIR`, jax.profiler.start_trace, or the profiler server), every span
+also opens a TraceMe of its own name on its own thread, carrying its
+scalar attrs as metadata, so the program's spans sit on the xplane's
+host plane on the same clock as the device ops they wait on; its
+duration also lands in the registry's capture ledger
+(`capture_totals()`). The check is one TraceMe.is_enabled() call per
+span, made only once jax is imported. `record_span` (timed after the
+fact) is not mirrored: jax's own compile events are in the capture.
 """
 
 from __future__ import annotations
@@ -35,10 +45,9 @@ from __future__ import annotations
 import collections
 import threading
 import time
-from contextlib import nullcontext
 
 from ..utils import envvars
-from .registry import get_registry
+from .registry import get_registry, trace_me
 
 _tls = threading.local()
 _ring_lock = threading.Lock()
@@ -46,8 +55,9 @@ _ring_lock = threading.Lock()
 _ENABLED = envvars.get_bool("TPU_IR_TRACE")
 _SAMPLE_N = envvars.get_int("TPU_IR_TRACE_SAMPLE")
 _RING = collections.deque(maxlen=envvars.get_int("TPU_IR_TRACE_RING"))
-_JAX_ANNOTATE = envvars.get_bool("TPU_IR_JAX_TRACE")
 _root_seq = 0
+_capture_check = get_registry().capture_check
+_observe = get_registry().observe
 # Root-close hooks: callables fired with every COMPLETED root span,
 # unconditionally — BEFORE and independent of the ring's 1-in-N
 # sampling, because a subscriber (obs/disttrace.py) applies its own
@@ -58,10 +68,9 @@ _root_hooks: list = []
 
 
 def configure(enabled: bool | None = None, sample: int | None = None,
-              ring_capacity: int | None = None,
-              jax_annotations: bool | None = None) -> None:
+              ring_capacity: int | None = None) -> None:
     """Runtime overrides of the TPU_IR_TRACE* env knobs (tests, REPLs)."""
-    global _ENABLED, _SAMPLE_N, _RING, _JAX_ANNOTATE
+    global _ENABLED, _SAMPLE_N, _RING
     if enabled is not None:
         _ENABLED = enabled
     if sample is not None:
@@ -69,8 +78,6 @@ def configure(enabled: bool | None = None, sample: int | None = None,
     if ring_capacity is not None:
         with _ring_lock:
             _RING = collections.deque(_RING, maxlen=max(1, ring_capacity))
-    if jax_annotations is not None:
-        _JAX_ANNOTATE = jax_annotations
 
 
 def enabled() -> bool:
@@ -82,7 +89,7 @@ class Span:
 
     __slots__ = ("name", "attrs", "start_ns", "dur_ns", "thread_id",
                  "thread_name", "wall_time", "children", "error",
-                 "_is_root")
+                 "_is_root", "_tm")
 
     def __init__(self, name: str, attrs: dict):
         self.name = name
@@ -96,6 +103,7 @@ class Span:
         self.children: list[Span] = []
         self.error: str | None = None
         self._is_root = False
+        self._tm = None  # the open TraceMe while a capture records
 
     def set(self, key: str, value) -> None:
         """Annotate the span (service level, breaker state, ...)."""
@@ -109,11 +117,17 @@ class Span:
         if self._is_root:
             self.wall_time = time.time()
         stack.append(self)
+        if _capture_check():
+            self._tm = _capture_span(self.name, self.attrs)
         self.start_ns = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         self.dur_ns = time.perf_counter_ns() - self.start_ns
+        tm = self._tm
+        if tm is not None:
+            tm.__exit__(None, None, None)
+            self._tm = None
         if exc is not None:
             self.error = repr(exc)
         stack = getattr(_tls, "stack", None)
@@ -122,7 +136,7 @@ class Span:
         parent = stack[-1] if stack else None
         if parent is not None:
             parent.children.append(self)
-        get_registry().observe(self.name, self.dur_ns / 1e9)
+        _observe(self.name, self.dur_ns / 1e9, tm is not None)
         if self._is_root:
             _push_root(self)
         return False
@@ -215,6 +229,23 @@ def current_root() -> Span | None:
     return stack[0] if stack else None
 
 
+def current_tree() -> dict | None:
+    """current_root() as a JSON-ready tree with the spans still open
+    under it on this thread nested in, each the last child of the one
+    before and marked "open" — a mid-flight snapshot of where the
+    thread is (to_dict alone shows only closed children)."""
+    stack = getattr(_tls, "stack", None)
+    if not stack:
+        return None
+    tree = node = stack[0].to_dict()
+    for span in stack[1:]:
+        child = span.to_dict()
+        child["open"] = True
+        node.setdefault("children", []).append(child)
+        node = child
+    return tree
+
+
 class _Attach:
     __slots__ = ("_parent", "_saved")
 
@@ -276,13 +307,18 @@ def clear_traces() -> None:
         _RING.clear()
 
 
-def kernel_annotation(name: str):
-    """Opt-in jax.profiler named region around a kernel dispatch: with
-    TPU_IR_JAX_TRACE=1 (or configure(jax_annotations=True)) the scoring
-    dispatches show up as named spans in an xprof/tensorboard capture
-    (`--profile DIR`); otherwise a free nullcontext."""
-    if not _JAX_ANNOTATE:
-        return nullcontext()
-    import jax
+def _capture_span(name: str, attrs: dict):
+    """Open the TraceMe that puts a span into the running capture: its
+    own name, its str/int/float attrs as metadata."""
+    tm = trace_me()(name, **{k: v for k, v in attrs.items()
+                            if isinstance(v, (str, int, float))})
+    tm.__enter__()
+    return tm
 
-    return jax.profiler.TraceAnnotation(name)
+
+def capture_totals() -> dict:
+    """The program's own totals for the newest profiler capture (see
+    TelemetryRegistry.capture_totals): every span and histogram
+    observation and every counter increment made while it recorded."""
+    return get_registry().capture_totals()
+

@@ -83,14 +83,18 @@ class TieredPostings(NamedTuple):
         the resident strip dtype: "bfloat16" halves the HBM footprint
         for compressed indexes whose tfs round-trip bf16 exactly (the
         scorer checks that before asking); the kernels widen to fp32 at
-        the weight-curve entry, so scores stay bit-identical."""
+        the weight-curve entry, so scores stay bit-identical. The
+        scatter, its compile included, is the device half of the layout
+        build: a `load.layout` span."""
+        from ..obs import trace as obs_trace
         from ..utils.transfer import stream_to_device
 
-        return _densify_hot(
-            stream_to_device(self.hot_rows),
-            stream_to_device(self.hot_docs),
-            stream_to_device(self.hot_vals),
-            num_hot=self.num_hot, width=self.hot_width, dtype=dtype)
+        coo = (stream_to_device(self.hot_rows),
+               stream_to_device(self.hot_docs),
+               stream_to_device(self.hot_vals))
+        with obs_trace("load.layout", layout="hot_strip"):
+            return _densify_hot(*coo, num_hot=self.num_hot,
+                                width=self.hot_width, dtype=dtype)
 
 
 @partial(jax.jit, static_argnames=("num_hot", "width", "dtype"))

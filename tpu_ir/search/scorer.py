@@ -26,7 +26,6 @@ import numpy as np
 
 from .. import faults
 from ..analysis.native import make_analyzer
-from ..obs import kernel_annotation
 from ..obs import trace as obs_trace
 from ..collection import KGRAM_SEP, DocnoMapping, Vocab, kgram_terms
 from ..index import format as fmt
@@ -412,11 +411,27 @@ class Scorer:
              deadline_s: float | None = None,
              verify_integrity: bool = True,
              doc_range: tuple | None = None) -> "Scorer":
+        """Load an index dir for serving, the whole load one `load` span
+        with its stages as children: load.read (side artifacts and part
+        shards, load.verify inside), load.assemble, load.layout,
+        load.cache_write (cache miss only) and load.h2d."""
         if layout not in ("auto", "dense", "sparse", "sharded"):
             # fail before any IO — a typo'd layout should not cost the
             # minutes-long shard read + CSR assembly of a large index
             raise ValueError(f"unknown layout {layout!r}; expected "
                              "'auto', 'dense', 'sparse' or 'sharded'")
+        with obs_trace("load", layout=layout):
+            return cls._load(index_dir, layout=layout,
+                             compat_int_idf=compat_int_idf, prune=prune,
+                             deadline_s=deadline_s,
+                             verify_integrity=verify_integrity,
+                             doc_range=doc_range)
+
+    @classmethod
+    def _load(cls, index_dir: str, *, layout: str, compat_int_idf: bool,
+              prune: bool, deadline_s: float | None,
+              verify_integrity: bool,
+              doc_range: tuple | None) -> "Scorer":
         from .. import enable_compilation_cache
 
         # the serving path compiles ~20 programs (layout scatter, top-k
@@ -431,26 +446,28 @@ class Scorer:
         from ..obs.server import register_index_dir
 
         register_index_dir(index_dir)
-        if verify_integrity:
-            # side artifacts are small — verify their recorded checksums
-            # on every load. Part shards are verified BY the reads that
-            # consume them (verify-while-read inside _assemble_csr and
-            # the lazy pairs_loader — one streamed pass, not the old
-            # verify-then-read double scan). A serving-cache HIT skips
-            # part checks: any filesystem-API change to a part (rebuild,
-            # migrate, overwrite) bumps size/mtime_ns and misses into
-            # the verified path, but stat revalidation deliberately does
-            # NOT re-prove content, so stat-preserving media bit-rot
-            # rides a hit undetected until shard bytes actually stream
-            # (layout.py::_part_stat; TPU_IR_CACHE_REVALIDATE=crc forces
-            # content-proven hits).
-            with obs_trace("load.verify", files="side"):
-                fmt.verify_checksums(
-                    index_dir, meta,
-                    names=[fmt.DOCLEN, fmt.DOCNOS, fmt.VOCAB])
-        vocab = Vocab.load(os.path.join(index_dir, fmt.VOCAB))
-        mapping = DocnoMapping.load(os.path.join(index_dir, fmt.DOCNOS))
-        doc_len = np.load(os.path.join(index_dir, fmt.DOCLEN))
+        with obs_trace("load.read", files="side"):
+            if verify_integrity:
+                # side artifacts are small — verify their recorded checksums
+                # on every load. Part shards are verified BY the reads that
+                # consume them (verify-while-read inside _assemble_csr and
+                # the lazy pairs_loader — one streamed pass, not the old
+                # verify-then-read double scan). A serving-cache HIT skips
+                # part checks: any filesystem-API change to a part (rebuild,
+                # migrate, overwrite) bumps size/mtime_ns and misses into
+                # the verified path, but stat revalidation deliberately does
+                # NOT re-prove content, so stat-preserving media bit-rot
+                # rides a hit undetected until shard bytes actually stream
+                # (layout.py::_part_stat; TPU_IR_CACHE_REVALIDATE=crc forces
+                # content-proven hits).
+                with obs_trace("load.verify", files="side"):
+                    fmt.verify_checksums(
+                        index_dir, meta,
+                        names=[fmt.DOCLEN, fmt.DOCNOS, fmt.VOCAB])
+            vocab = Vocab.load(os.path.join(index_dir, fmt.VOCAB))
+            mapping = DocnoMapping.load(os.path.join(index_dir,
+                                                     fmt.DOCNOS))
+            doc_len = np.load(os.path.join(index_dir, fmt.DOCLEN))
 
         def load_pairs_verified():
             """Lazy CSR assembly for the cache fast path — parts may have
@@ -548,40 +565,45 @@ class Scorer:
                 save_sharded_serving_cache,
             )
 
-            pair_term = pair_term_from_df(df)  # per-shard df bincounts
-            sharded_layout = make_sharded_tiered(
-                pair_term, pair_doc, pair_tf, np.asarray(df),
-                np.asarray(doc_len), num_docs=meta.num_docs,
-                num_shards=len(jax.devices()))
+            with obs_trace("load.layout", layout="sharded"):
+                pair_term = pair_term_from_df(df)  # per-shard df bincounts
+                sharded_layout = make_sharded_tiered(
+                    pair_term, pair_doc, pair_tf, np.asarray(df),
+                    np.asarray(doc_len), num_docs=meta.num_docs,
+                    num_shards=len(jax.devices()))
             if save_cache:
-                norms = compute_doc_norms(pair_term, pair_doc, pair_tf,
-                                          df, meta.num_docs)
-                # one writer on a shared index dir: every process builds
-                # the same layout, process 0 persists it
-                if jax.process_index() == 0:
-                    save_sharded_serving_cache(index_dir, sharded_layout,
-                                               df, norms, meta=meta,
-                                               num_shards=len(
-                                                   jax.devices()))
+                with obs_trace("load.cache_write", layout="sharded"):
+                    norms = compute_doc_norms(pair_term, pair_doc, pair_tf,
+                                              df, meta.num_docs)
+                    # one writer on a shared index dir: every process
+                    # builds the same layout, process 0 persists it
+                    if jax.process_index() == 0:
+                        save_sharded_serving_cache(
+                            index_dir, sharded_layout, df, norms,
+                            meta=meta, num_shards=len(jax.devices()))
         elif resolved == "sparse":
             from ..index.blockmax import load_block_bounds
             from .layout import save_serving_cache
 
-            # the builders' block-max bounds artifact saves the bounds
-            # pass; corrupt copies quarantine and the pass recomputes
-            # (bounds are derived data — never a load failure)
-            bounds = load_block_bounds(index_dir, meta,
-                                       quarantine_corrupt=True)
-            tiers = build_tiered_layout(pair_doc, pair_tf, df,
-                                        num_docs=meta.num_docs,
-                                        block_bounds=bounds)
+            with obs_trace("load.layout", layout="sparse"):
+                # the builders' block-max bounds artifact saves the
+                # bounds pass; corrupt copies quarantine and the pass
+                # recomputes (bounds are derived data — never a load
+                # failure)
+                bounds = load_block_bounds(index_dir, meta,
+                                           quarantine_corrupt=True)
+                tiers = build_tiered_layout(pair_doc, pair_tf, df,
+                                            num_docs=meta.num_docs,
+                                            block_bounds=bounds)
             if save_cache:
-                # pair_term stays lazy: the norms pass derives each
-                # chunk's term ids from the df row starts instead of
-                # materializing the ~1 GB column (ISSUE 5 satellite)
-                norms = compute_doc_norms(None, pair_doc, pair_tf,
-                                          df, meta.num_docs)
-                save_serving_cache(index_dir, tiers, df, norms, meta=meta)
+                with obs_trace("load.cache_write", layout="sparse"):
+                    # pair_term stays lazy: the norms pass derives each
+                    # chunk's term ids from the df row starts instead of
+                    # materializing the ~1 GB column
+                    norms = compute_doc_norms(None, pair_doc, pair_tf,
+                                              df, meta.num_docs)
+                    save_serving_cache(index_dir, tiers, df, norms,
+                                       meta=meta)
         return cls(
             vocab=vocab, mapping=mapping,
             pair_term=pair_term, pair_doc=pair_doc,
@@ -1086,8 +1108,9 @@ class Scorer:
         overlap above, unchanged), then the wait for device completion is
         timed as the `dispatch.device` span — with the shim's
         dispatch.trace/dispatch.compile this decomposes the fixed
-        per-dispatch RTT — and one memory gauge sample lands after every
-        dispatch (device bytes_in_use/peak + host RSS)."""
+        per-dispatch RTT, up to the results' arrival on the host — and
+        one memory gauge sample lands after every dispatch (device
+        bytes_in_use/peak + host RSS)."""
         import jax
 
         from ..obs import profiling
@@ -1110,8 +1133,10 @@ class Scorer:
         issue_host_copies(flat_outs)  # in flight before the wait, as before
         with obs_trace("dispatch.device", blocks=len(outs)):
             jax.block_until_ready(flat_outs)
+            # the host copies issued above land here: their tail is a
+            # wait on the device transport too
+            flat = [np.asarray(a) for a in flat_outs]
         profiling.sample_memory()
-        flat = [np.asarray(a) for a in flat_outs]
         parts = [(flat[i], flat[i + 1]) for i in range(0, len(flat), 2)]
         if len(parts) == 1:
             return parts[0]
@@ -1266,7 +1291,8 @@ class Scorer:
                                                     hot_only=hot_only,
                                                     donate=donate),
                 (q, -1))
-        has_hot, n_free, mode = self._skip_plan(q)
+        with obs_trace("search.schedule", queries=len(q)):
+            has_hot, n_free, mode = self._skip_plan(q)
         if mode == "all_skip":
             self._ledger_skip_plan(len(q), n_free,
                                    -(-len(q) // block), 0)
@@ -1285,9 +1311,10 @@ class Scorer:
                 (q, -1))
         self._ledger_skip_plan(len(q), n_free, -(-n_free // block),
                                -(-(len(q) - n_free) // block))
-        order = self._schedule_order(has_hot)
-        inv = np.argsort(order, kind="stable")
-        qs = q[order]
+        with obs_trace("search.schedule", queries=len(q)):
+            order = self._schedule_order(has_hot)
+            inv = np.argsort(order, kind="stable")
+            qs = q[order]
         s1, d1 = self._group_dispatch(qs[:n_free], block,
                                       lambda qb: self._topk_device(
                                           qb, k, scoring, skip_hot=True,
@@ -1410,8 +1437,9 @@ class Scorer:
         `rungs x {skip, full}` per scoring model, walked once by the
         frontend's precompile, so no serving batch ever waits on XLA."""
         block = self._block_size()
-        has_hot = self._has_hot(q)
-        n_free = int((~has_hot).sum())
+        with obs_trace("search.schedule", queries=len(q)):
+            has_hot = self._has_hot(q)
+            n_free = int((~has_hot).sum())
 
         def skip_fn(qb):
             return self._topk_device(qb, k, scoring, skip_hot=True,
@@ -1451,9 +1479,10 @@ class Scorer:
         self._ledger_skip_plan(len(q), n_free,
                                max(-(-n_free // block), 1),
                                max(-(-(len(q) - n_free) // block), 1))
-        order = self._schedule_order(has_hot)
-        inv = np.argsort(order, kind="stable")
-        qs = q[order]
+        with obs_trace("search.schedule", queries=len(q)):
+            order = self._schedule_order(has_hot)
+            inv = np.argsort(order, kind="stable")
+            qs = q[order]
         s1, d1 = self._rung_dispatch(qs[:n_free], block, rungs, skip_fn)
         s2, d2 = self._rung_dispatch(qs[n_free:], block, rungs, full_fn)
         return (np.concatenate([s1, s2])[inv],
@@ -1728,7 +1757,9 @@ class Scorer:
     def _note_blockmax_stats(self, stats) -> None:
         """Queue one dispatch's (considered, masked, fallback) device
         triple; drained AFTER the batch's results are fetched so the
-        stats read never serializes the dispatch overlap."""
+        stats read never serializes the dispatch overlap (its host copy
+        is issued now, to arrive with the results)."""
+        stats.copy_to_host_async()
         with self._lazy_lock:
             self.__dict__.setdefault("_blockmax_pending", []).append(stats)
 
@@ -1764,12 +1795,10 @@ class Scorer:
 
         The "kernel" span times the jit call + injected hangs for THIS
         block (the dispatch is async on real hardware — completion cost
-        lands in the parent dispatch span's fetch); with TPU_IR_JAX_TRACE
-        the block also rides as a named region in jax.profiler captures."""
+        lands in the parent dispatch span's fetch); in a profiler
+        capture it carries the layout and scoring as metadata."""
         with obs_trace("kernel", layout=self.layout, scoring=scoring,
-                       rows=int(len(q_terms))), \
-                kernel_annotation(
-                    f"tpu_ir.topk.{self.layout}.{scoring}"):
+                       rows=int(len(q_terms))):
             return self._topk_device_raw(q_terms, k, scoring,
                                          skip_hot=skip_hot,
                                          hot_only=hot_only,
@@ -2090,8 +2119,7 @@ class Scorer:
                 # degradation (the tiered/sharded fallback matrix caught
                 # exactly this gap)
                 with obs_trace("kernel", layout="sharded",
-                               scoring="rerank", rows=int(len(q))), \
-                        kernel_annotation("tpu_ir.rerank.sharded"):
+                               scoring="rerank", rows=int(len(q))):
                     faults.maybe_hang("score.hang")
                     if faults.should_fire(
                             "score.device_loss") is not None:
@@ -2195,14 +2223,18 @@ class Scorer:
                              "slot_meta) cannot contain phrase queries "
                              "— the coalescing frontend routes them "
                              "solo")
-        plain_iter = iter(self._search_batch_plain(
-            plain, k=k, scoring=scoring, return_docids=return_docids,
-            rerank=rerank, prox=prox, deadline_s=deadline_s,
-            force_host=force_host, hot_only=hot_only,
-            explain_k=explain_k, explain_ks=explain_ks, pad_to=pad_to,
-            width_floor=width_floor, rung_ladder=rung_ladder,
-            donate_queries=donate_queries,
-            slot_meta=slot_meta) if plain else [])
+        plain_out = []
+        if plain:
+            with obs_trace("search", queries=len(plain)):
+                plain_out = self._search_batch_plain(
+                    plain, k=k, scoring=scoring,
+                    return_docids=return_docids, rerank=rerank, prox=prox,
+                    deadline_s=deadline_s, force_host=force_host,
+                    hot_only=hot_only, explain_k=explain_k,
+                    explain_ks=explain_ks, pad_to=pad_to,
+                    width_floor=width_floor, rung_ladder=rung_ladder,
+                    donate_queries=donate_queries, slot_meta=slot_meta)
+        plain_iter = iter(plain_out)
         return [self._search_phrase(t, k=k, scoring=scoring,
                                     slop=phrase_slop,
                                     return_docids=return_docids,
@@ -2221,15 +2253,16 @@ class Scorer:
         slot_meta: Sequence[dict] | None = None,
     ) -> list[SearchResult]:
         t0 = time.perf_counter()
-        q = self.analyze_queries(texts, width_floor=width_floor)
-        if pad_to is not None and pad_to > len(q):
-            # the coalescing rung ladder: pad the ROW axis with -1 rows
-            # (score exact 0.0, top-k all-empty) so every dispatch
-            # reuses one of the precompiled batch shapes; the pad rows'
-            # outputs are sliced off below — no SearchResult, no
-            # querylog entry, no caller ever sees them
-            q = np.vstack([q, np.full((pad_to - len(q), q.shape[1]),
-                                      -1, np.int32)])
+        with obs_trace("search.analyze", queries=len(texts)):
+            q = self.analyze_queries(texts, width_floor=width_floor)
+            if pad_to is not None and pad_to > len(q):
+                # the coalescing rung ladder: pad the ROW axis with -1
+                # rows (score exact 0.0, top-k all-empty) so every
+                # dispatch reuses one of the precompiled batch shapes;
+                # the pad rows' outputs are sliced off below — no
+                # SearchResult, no querylog entry, no caller sees them
+                q = np.vstack([q, np.full((pad_to - len(q), q.shape[1]),
+                                          -1, np.int32)])
         t_analyzed = time.perf_counter()
         if rerank:
             from .phrase import PROX_DEPTH

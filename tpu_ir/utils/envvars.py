@@ -83,8 +83,6 @@ _declare("TPU_IR_TRACE_SAMPLE", "int", 1,
          minimum=1)
 _declare("TPU_IR_TRACE_RING", "int", 64,
          "capacity of the recent-traces ring buffer", "§9", minimum=1)
-_declare("TPU_IR_JAX_TRACE", "bool", False,
-         "1 wraps kernel dispatches in jax.profiler named regions", "§9")
 _declare("TPU_IR_FLIGHT_DIR", "str", None,
          "flight-recorder artifact directory (default: system temp)", "§9")
 _declare("TPU_IR_FLIGHT_INTERVAL", "float", 30.0,
