@@ -133,6 +133,43 @@ def test_coalesced_batch_bit_exact_rerank(scorers):
         assert list(got) == list(want), text
 
 
+@pytest.mark.parametrize("scoring", ["tfidf", "bm25"])
+def test_coalesced_batch_bit_exact_with_the_chunk_stream(
+        index_dir, scorers, monkeypatch, scoring):
+    """With an 8-posting chunk width every cold tier of 8 or more slots
+    streams: coalesced (worst-case capacity) == solo (capacity fitted
+    to the block) bit for bit, explain decomposes the streamed
+    scores exactly, the stream agrees with the per-tier stages, and the
+    dispatches count their postings and lanes."""
+    from tpu_ir.search import layout
+
+    monkeypatch.setattr(layout, "COLD_CHUNK", 8)
+    s = Scorer.load(index_dir, layout="sparse")
+    assert s.cold_chunks is not None and s.cold_chunks.docs.shape[1] == 8
+    reg = get_registry()
+    before = (reg.get("cold.chunk_postings"), reg.get("cold.chunk_slots"))
+    solo = [_solo(s, t, scoring=scoring) for t in QUERIES]
+    streamed = reg.get("cold.chunk_postings") - before[0]
+    lanes = reg.get("cold.chunk_slots") - before[1]
+    assert 0 < streamed <= lanes
+    for size in (1, 3, len(QUERIES)):
+        batched = _batched(s, QUERIES[:size], scoring=scoring)
+        for got, want, text in zip(batched, solo[:size], QUERIES):
+            assert list(got) == list(want), (scoring, text)
+    plain = scorers["sparse"]
+    assert plain.cold_chunks is None
+    for got, text in zip(solo, QUERIES):
+        want = _solo(plain, text, scoring=scoring)
+        assert [d for d, _ in got] == [d for d, _ in want], text
+        np.testing.assert_allclose([v for _, v in got],
+                                   [v for _, v in want], rtol=1e-6)
+    res = s.search_batch(QUERIES[:3], k=5, scoring=scoring,
+                         explain_k=3)
+    for r in res:
+        for e, (_, score) in zip(r.explain, r):
+            assert e["contribution_sum"] == e["score"] == score
+
+
 def test_donated_query_twins_bit_exact(scorers, monkeypatch):
     """TPU_IR_BATCH_DONATE=1 forces the donated-query kernel twins even
     on CPU (where XLA ignores the donation with a warning): identical
@@ -333,7 +370,26 @@ def test_precompiled_ladder_closes_the_shape_universe(index_dir):
     serving performs ZERO jit compiles — stronger than the zero-
     recompiles acceptance pin: batch content (occupancy, scheduling
     split, query mix) cannot mint a single new XLA program."""
-    s = Scorer.load(index_dir, layout="sparse")
+    _assert_steady_state_compiles_nothing(
+        Scorer.load(index_dir, layout="sparse"))
+
+
+@pytest.mark.parametrize("prune", [True, False])
+def test_precompiled_ladder_closes_the_shape_universe_with_the_chunk_stream(
+        index_dir, monkeypatch, prune):
+    """The same closed universe when the big cold tiers stream as chunks
+    (an 8-posting width, so the fixture's tiers stream): rung-padded
+    batches dispatch at the worst-case chunk capacity whatever their
+    content, on the MaxScore-scheduled path and with pruning off."""
+    from tpu_ir.search import layout
+
+    monkeypatch.setattr(layout, "COLD_CHUNK", 8)
+    s = Scorer.load(index_dir, layout="sparse", prune=prune)
+    assert s.cold_chunks is not None
+    _assert_steady_state_compiles_nothing(s)
+
+
+def _assert_steady_state_compiles_nothing(s):
     fe = ServingFrontend(s, ServingConfig(
         max_concurrency=6, max_queue=16, coalesce=True,
         batch_ladder=LADDER, batch_width=WIDTH))

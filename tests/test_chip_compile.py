@@ -23,11 +23,13 @@ from tpu_ir.ops.pallas_scoring import (
 )
 from tpu_ir.ops.postings import round_cap
 from tpu_ir.ops.scoring import (
+    ColdChunks,
     blockmax_cand_blocks,
     bm25_topk_blockmax,
     bm25_topk_tiered,
     tfidf_topk_tiered,
 )
+from tpu_ir.search.layout import COLD_CHUNK
 
 N_DOCS = 100_000
 VOCAB = 200_000
@@ -121,6 +123,25 @@ def test_bm25_topk_blockmax(chip):
         spec((), jnp.int32, chip), spec((HOT, nblk), jnp.float32, chip),
         num_docs=N_DOCS, width=BLOCK_W,
         cand_blocks=blockmax_cand_blocks(10, N_DOCS, BLOCK_W), k=10)
+    assert fits_one_chip(lowered.compile())
+
+
+def test_bm25_topk_blockmax_chunk_stream(chip):
+    """The production deep-k kernel with the 2,048-slot tier streamed as
+    chunks, at the capacity a block of 4,096 chunks is dispatched at."""
+    ops = tiered_operands(chip)
+    nblk = num_blocks(N_DOCS, BLOCK_W)
+    rows = sum(v * c // COLD_CHUNK for v, c in TIERS if c % COLD_CHUNK == 0)
+    chunks = ColdChunks(spec((rows, COLD_CHUNK), jnp.int32, chip),
+                        spec((rows, COLD_CHUNK), jnp.int32, chip),
+                        spec((VOCAB,), jnp.int32, chip),
+                        spec((VOCAB,), jnp.int32, chip))
+    lowered = bm25_topk_blockmax.lower(
+        *ops, spec((N_DOCS + 1,), jnp.int32, chip),
+        spec((), jnp.int32, chip), spec((HOT, nblk), jnp.float32, chip),
+        num_docs=N_DOCS, width=BLOCK_W,
+        cand_blocks=blockmax_cand_blocks(1000, N_DOCS, BLOCK_W), k=1000,
+        chunks=chunks, n_chunks=4096)
     assert fits_one_chip(lowered.compile())
 
 

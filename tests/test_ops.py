@@ -817,3 +817,189 @@ def test_log_is_f32_accurate():
     y = np.geomspace(1e-9, 1e6, 2000).astype(np.float32)
     np.testing.assert_allclose(np.asarray(_log1p(jnp.asarray(y))),
                                np.log1p(y.astype(np.float64)), rtol=5e-7)
+
+
+# -- the cold chunk stream ---------------------------------------------------
+
+STREAM_C = 32  # chunk width small enough for a 1,500-doc corpus
+# dfs around the chunk width: term 0 hot; 1 = C; 2 = C + 1; 3 = cap 128;
+# 4 and 5 two terms in the 128 tier; 6 = cap 512; 7 the 128 tier again;
+# 8-13 small per-tier terms (12 has df 0)
+STREAM_DFS = [1200, 32, 33, 128, 100, 120, 512, 40, 5, 3, 1, 7, 0, 2]
+STREAM_BIG = np.array([[1, 2, 3, -1],         # dfs C, C+1 and cap
+                       [4, 5, 3, 0],          # two terms in one tier + hot
+                       [-1, -1, -1, -1],      # a pad row
+                       [len(STREAM_DFS) + 5, 12, 9, 1],  # OOV, df 0
+                       [6, 6, 7, 8]], np.int32)  # a repeated term
+STREAM_SMALL = np.array([[8, 9, 10, -1], [11, 13, -1, -1]], np.int32)
+
+
+@pytest.fixture(scope="module")
+def stream_corpus():
+    from tpu_ir.ops.scoring import ColdChunks, dense_tf_matrix
+    from tpu_ir.search import layout
+
+    rng = np.random.default_rng(24)
+    ndocs = 1500
+    pt, pd, ptf = [], [], []
+    for tid, df_t in enumerate(STREAM_DFS):
+        docs = rng.choice(ndocs, df_t, replace=False) + 1
+        tfs = rng.integers(1, 9, df_t)
+        order = np.lexsort((docs, -tfs))
+        pt += [tid] * df_t
+        pd += docs[order].tolist()
+        ptf += tfs[order].tolist()
+    pt, pd, ptf = (np.array(a, np.int32) for a in (pt, pd, ptf))
+    df = np.bincount(pt, minlength=len(STREAM_DFS)).astype(np.int32)
+    lay = layout.build_tiered_layout(pd, ptf, df, num_docs=ndocs,
+                                     hot_budget=ndocs + 1)  # one hot row
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(layout, "COLD_CHUNK", STREAM_C)
+        plan = layout.cold_chunk_plan(lay, df)
+    dev = lambda arrs: tuple(jnp.asarray(a) for a in arrs)  # noqa: E731
+    tier_docs, tier_tfs = dev(lay.tier_docs), dev(lay.tier_tfs)
+    doc_len = np.zeros(ndocs + 1, np.int32)
+    doc_len[1:] = rng.integers(5, 60, ndocs)
+    return dict(
+        ndocs=ndocs, df=df, doc_len=doc_len, plan=plan, lay=lay,
+        chunks=ColdChunks(*dev(layout.cold_chunk_table(lay, plan)
+                               + plan[1:3])),
+        tiered=(jnp.asarray(lay.hot_rank), lay.hot_device(),
+                jnp.asarray(lay.tier_of), jnp.asarray(lay.row_of),
+                tier_docs, tier_tfs, jnp.asarray(df)),
+        tf_mat=dense_tf_matrix(jnp.asarray(pt), jnp.asarray(pd),
+                               jnp.asarray(ptf), vocab_size=len(df),
+                               num_docs=ndocs),
+        mat=dense_doc_matrix(jnp.asarray(pt), jnp.asarray(pd),
+                             jnp.asarray(ptf), vocab_size=len(df),
+                             num_docs=ndocs))
+
+
+def _stream_need(corpus, q):
+    count = corpus["plan"][2]
+    valid = (q >= 0) & (q < len(count))
+    return int(np.where(valid, count[np.where(valid, q, 0)], 0).sum())
+
+
+def _stream_kernels(corpus, scoring, k):
+    """(dense, tiered) callables of one query block, the tiered one
+    taking the chunk-stream keyword arguments."""
+    from tpu_ir.ops.scoring import (bm25_topk_dense, bm25_topk_tiered,
+                                    tfidf_topk_tiered)
+
+    c, n = corpus, jnp.int32(corpus["ndocs"])
+    if scoring == "bm25":
+        dl = jnp.asarray(c["doc_len"])
+        return (lambda q: bm25_topk_dense(q, c["tf_mat"], jnp.asarray(
+                    c["df"]), dl, n, k=k),
+                lambda q, **kw: bm25_topk_tiered(
+                    q, *c["tiered"], dl, n, num_docs=c["ndocs"], k=k, **kw))
+    return (lambda q: tfidf_topk_dense(q, c["mat"], jnp.asarray(c["df"]),
+                                       n, k=k),
+            lambda q, **kw: tfidf_topk_tiered(
+                q, *c["tiered"], n, num_docs=c["ndocs"], k=k, **kw))
+
+
+def test_chunk_plan_streams_the_tiers_whole_chunks_divide(stream_corpus):
+    """Tiers whose capacity is a multiple of C stream; each streamed
+    term starts at its own chunk row and streams ceil(df / C) rows
+    holding exactly its postings; hot, small-tier and df-0 terms
+    stream nothing."""
+    lay, df = stream_corpus["lay"], stream_corpus["df"]
+    streamed, row0, count, c = stream_corpus["plan"]
+    caps = [a.shape[1] for a in lay.tier_docs]
+    assert c == STREAM_C
+    assert streamed == [i for i, cap in enumerate(caps) if cap % c == 0]
+    assert [caps[i] for i in streamed] == [32, 128, 512]
+    docs = np.asarray(stream_corpus["chunks"].docs)
+    tfs = np.asarray(stream_corpus["chunks"].tfs)
+    for tid, df_t in enumerate(STREAM_DFS):
+        t = lay.tier_of[tid]
+        if t < 0 or caps[t] % c:
+            assert count[tid] == 0, tid
+            continue
+        assert count[tid] == -(-df_t // c), tid
+        rows = slice(row0[tid], row0[tid] + count[tid])
+        want = np.zeros(count[tid] * c, np.int64)
+        want[:df_t] = lay.tier_docs[t][lay.row_of[tid], :df_t]
+        np.testing.assert_array_equal(docs[rows].ravel(), want)
+        assert (tfs[rows].ravel()[:df_t] > 0).all()
+        assert not tfs[rows].ravel()[df_t:].any()
+
+
+@pytest.mark.parametrize("scoring", ["bm25", "tfidf"])
+@pytest.mark.parametrize("k", [10, 1000])
+def test_chunk_stream_matches_dense_and_per_tier_stages(stream_corpus,
+                                                        scoring, k):
+    """The stream against the dense kernel and the per-tier stages it
+    replaces: rank-equal, scores within 1e-6 relative. Capacities: the
+    exact need, its bucket and the worst case (the bucket minimum and
+    the worst case for a block with no streamed term)."""
+    from tpu_ir.ops.scoring import chunk_bucket
+
+    dense, tiered = _stream_kernels(stream_corpus, scoring, k)
+    chunks = stream_corpus["chunks"]
+    widest = 512 // STREAM_C
+    for q in (STREAM_BIG, STREAM_SMALL):
+        need = _stream_need(stream_corpus, q)
+        caps = ({need, chunk_bucket(need), q.size * widest}
+                if need else {chunk_bucket(0), q.size * widest})
+        qd = jnp.asarray(q)
+        s_d, d_d = (np.asarray(a) for a in dense(qd))
+        s_t, d_t = (np.asarray(a) for a in tiered(qd))
+        assert s_d[0, 0] > 0
+        for cap in caps:
+            s, d = (np.asarray(a) for a in tiered(qd, chunks=chunks,
+                                                  n_chunks=cap))
+            np.testing.assert_array_equal(d, d_d, err_msg=str(cap))
+            np.testing.assert_array_equal(d, d_t, err_msg=str(cap))
+            np.testing.assert_allclose(s, s_d, rtol=1e-6, err_msg=str(cap))
+            np.testing.assert_allclose(s, s_t, rtol=1e-6, err_msg=str(cap))
+
+
+@pytest.mark.parametrize("scoring", ["bm25", "tfidf"])
+def test_chunk_stream_row_is_independent_of_its_block(stream_corpus,
+                                                      scoring):
+    """A query's floats do not depend on what shares its block or on
+    the capacity: solo at its own need, inside the block, and at the
+    worst case (the coalescer's closed shapes) agree bit for bit."""
+    from tpu_ir.ops.scoring import chunk_bucket
+
+    _, tiered = _stream_kernels(stream_corpus, scoring, 1000)
+    chunks = stream_corpus["chunks"]
+    need = _stream_need(stream_corpus, STREAM_BIG)
+    block = np.asarray(tiered(jnp.asarray(STREAM_BIG), chunks=chunks,
+                              n_chunks=chunk_bucket(need))[0])
+    worst = np.asarray(tiered(jnp.asarray(STREAM_BIG), chunks=chunks,
+                              n_chunks=STREAM_BIG.size * 512 // STREAM_C)[0])
+    np.testing.assert_array_equal(block, worst)
+    for i, row in enumerate(STREAM_BIG):
+        q = row[None, :]
+        solo = np.asarray(tiered(jnp.asarray(q), chunks=chunks,
+                                 n_chunks=max(_stream_need(
+                                     stream_corpus, q), 1))[0])
+        np.testing.assert_array_equal(solo[0], block[i], err_msg=str(i))
+
+
+def test_chunk_capacity_bucket_reuses_one_program(stream_corpus):
+    """Blocks whose needs fall in one capacity bucket run one compiled
+    program; a need past the bucket compiles one more."""
+    from tpu_ir import obs
+    from tpu_ir.ops.scoring import CHUNK_MIN_BUCKET, bm25_topk_tiered, \
+        chunk_bucket
+
+    _, tiered = _stream_kernels(stream_corpus, "bm25", 10)
+    chunks = stream_corpus["chunks"]
+    q_a = np.array([[1, -1, -1, -1]], np.int32)     # 1 chunk
+    q_b = np.array([[3, 7, -1, -1]], np.int32)      # 4 + 2 chunks
+    q_c = np.array([[6, 6, 3, 2]], np.int32)        # 16 + 16 + 4 + 2
+    needs = [_stream_need(stream_corpus, q) for q in (q_a, q_b, q_c)]
+    assert needs[0] != needs[1] and max(needs[:2]) <= CHUNK_MIN_BUCKET
+    assert chunk_bucket(needs[2]) > CHUNK_MIN_BUCKET
+    bm25_topk_tiered.clear_cache()
+    reg = obs.get_registry()
+    seen = []
+    for q, need in zip((q_a, q_b, q_c), needs):
+        tiered(jnp.asarray(q), chunks=chunks, n_chunks=chunk_bucket(need))
+        seen.append(reg.get("compile.count"))
+    assert seen[1] == seen[0] and seen[2] == seen[1] + 1, seen

@@ -255,12 +255,15 @@ class ProfiledJit:
     def _abstract(a):
         """The ShapeDtypeStruct twin of one argument: arrays become
         specs (no data — safe even when the real call DONATED the
-        buffer), statics pass through, sequences map recursively."""
+        buffer), statics pass through, sequences map recursively (a
+        NamedTuple keeps its type, so the kernel sees its fields)."""
         shape = getattr(a, "shape", None)
         if shape is not None and hasattr(a, "dtype"):
             import jax
 
             return jax.ShapeDtypeStruct(tuple(shape), a.dtype)
+        if isinstance(a, tuple) and hasattr(a, "_fields"):
+            return type(a)(*(ProfiledJit._abstract(x) for x in a))
         if isinstance(a, (tuple, list)):
             return tuple(ProfiledJit._abstract(x) for x in a)
         return a

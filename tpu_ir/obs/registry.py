@@ -228,6 +228,12 @@ PRUNE_COUNTER_NAMES = (
     "blockmax.saved_dispatches", "blockmax.fallback_dispatches",
 )
 
+# Cold chunk stream (ops/scoring.py _chunk_stream): postings the big cold
+# tiers' chunks hold for the dispatched blocks' terms, and the lanes
+# dispatched for them (capacity x chunk width); postings / slots is the
+# stream's fill.
+COLD_CHUNK_COUNTER_NAMES = ("cold.chunk_postings", "cold.chunk_slots")
+
 # Generation-keyed exact-hit result cache (ISSUE 15,
 # serving/result_cache.py): hit/miss the lookup verdicts (hit_fraction =
 # hit / (hit + miss)), evict the LRU displacements under the bounded
@@ -308,7 +314,8 @@ DECLARED_COUNTERS = tuple(f"fault.{s}" for s in FAULT_SITES) + (
     "load.h2d_bytes",
 ) + (COMPILE_COUNTER_NAMES + QUERYLOG_COUNTER_NAMES + BATCH_COUNTER_NAMES
      + ROUTER_COUNTER_NAMES + BUILD_COUNTER_NAMES + INGEST_COUNTER_NAMES
-     + PRUNE_COUNTER_NAMES + CACHE_COUNTER_NAMES + SCALE_COUNTER_NAMES
+     + PRUNE_COUNTER_NAMES + COLD_CHUNK_COUNTER_NAMES
+     + CACHE_COUNTER_NAMES + SCALE_COUNTER_NAMES
      + DISTTRACE_COUNTER_NAMES + TIMESERIES_COUNTER_NAMES
      + COMPRESS_COUNTER_NAMES)
 # "request" (the root span, all levels pooled) rides alongside the

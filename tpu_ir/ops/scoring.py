@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import math
 from functools import partial
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -36,8 +37,37 @@ PAD_QTERM = -1
 
 # cold tiers at least this wide run under a whole-block lax.cond skip (the
 # stage costs B*L*P_t even when no query term lands in it); narrower tiers
-# are nearly always hit, where the cond only adds sync overhead
+# are nearly always hit, where the cond only adds sync overhead. Only the
+# per-tier stage uses it, which the big tiers take only where no chunk
+# stream is passed: the sharded layout (parallel/sharded_tiered.py)
 COND_TIER_MIN_CAP = 4096
+
+# smallest chunk-stream capacity (in chunks) a dispatch is compiled for:
+# below it a block's own postings are too few for another program to pay
+CHUNK_MIN_BUCKET = 16
+
+
+class ColdChunks(NamedTuple):
+    """The big cold tiers as one table of fixed-size posting chunks
+    (search/layout.py `cold_chunk_table`): every tier whose capacity is
+    a multiple of the chunk width C, viewed as [V_t * P_t / C, C] rows
+    and concatenated. A term's postings are `count` consecutive rows
+    from `row0`; lanes past its df hold tf 0, like tier padding. The
+    kernels never read a streamed tier's own arrays when given this, so
+    a caller may pass [0, P_t] placeholders for them."""
+
+    docs: jax.Array    # [R, C] docnos in the tiers' dtype, 0 = empty lane
+    tfs: jax.Array     # [R, C] raw tfs, 0 = empty lane
+    row0: jax.Array    # int32 [V]: the term's first chunk row
+    count: jax.Array   # int32 [V]: ceil(df / C) for streamed terms, else 0
+
+
+def chunk_bucket(need: int) -> int:
+    """The static capacity a block needing `need` chunks is dispatched
+    at: the next power of two, at least CHUNK_MIN_BUCKET (cf. the query
+    width bucketing in Scorer.analyze_queries)."""
+    return max(CHUNK_MIN_BUCKET, 1 << max(int(need) - 1, 0).bit_length())
+
 
 # MaxScore candidate-set width: when the hot-strip stage is pruned, the
 # top MAXSCORE_CAND docs by cold partial score are the only ones that get
@@ -319,9 +349,19 @@ def _tiered_scores(q_terms, hot_rank, hot_tfs, tier_of, row_of, tier_docs,
                    tier_tfs, q_weight, *, num_docs, hot_weight_fn,
                    cold_weight_fn, hot_cell_fn=None, hot_max_w=None,
                    prune_k=None, with_stats=False, skip_hot=False,
-                   skip_cold=False):
-    """Shared tiered accumulation: hot-strip einsum + one masked
-    gather/scatter-add per df tier (see search/layout.py for the layout).
+                   skip_cold=False, chunks=None, n_chunks=None):
+    """Shared tiered accumulation: hot-strip einsum, one masked
+    gather/scatter-add per small df tier, and one chunk stream for the
+    big tiers (see search/layout.py for the layout).
+
+    `chunks` (a ColdChunks table, or None) takes every tier whose
+    capacity is a multiple of its chunk width off the per-tier stages:
+    those tiers cost B*L*P_t each per dispatch whatever lands in them,
+    while the stream moves only the chunks the block's own terms hold
+    (`_chunk_stream`). `n_chunks` (static, required with `chunks`) is
+    the stream's capacity, which must hold the block's need: the Scorer
+    passes the block's bucketed need, or where the compiled set must
+    stay closed the worst case B*L*(widest streamed capacity / C).
 
     `hot_weight_fn(strip)` maps the raw-tf hot strip [H, D+1] (doc axis
     last) to per-cell score contributions; `cold_weight_fn(tfs, docs)` does
@@ -391,8 +431,13 @@ def _tiered_scores(q_terms, hot_rank, hot_tfs, tier_of, row_of, tier_docs,
     def add_cold(acc_q, slots_q, w_q):
         return acc_q.at[slots_q.ravel()].add(w_q.ravel(), mode="drop")
 
+    if chunks is not None and n_chunks is None:
+        raise ValueError("a chunk stream needs its static n_chunks")
+    chunk_w = None if chunks is None else chunks.docs.shape[1]
     for i, (tdocs, ttfs) in enumerate(
             () if skip_cold else zip(tier_docs, tier_tfs)):
+        if chunk_w is not None and tdocs.shape[1] % chunk_w == 0:
+            continue                                 # streamed below
         in_tier = (tof == i) & q_valid & ~is_hot             # [B, L]
 
         def do_tier(s, in_tier=in_tier, tdocs=tdocs, ttfs=ttfs):
@@ -418,6 +463,12 @@ def _tiered_scores(q_terms, hot_rank, hot_tfs, tier_of, row_of, tier_docs,
         else:
             scores = do_tier(scores)
 
+    if chunks is not None and not skip_cold:
+        scores = _chunk_stream(
+            scores, chunks, safe_q, q_valid & ~is_hot, q_w,
+            n_chunks=n_chunks, num_docs=num_docs,
+            cold_weight_fn=cold_weight_fn)
+
     if skip_hot:
         return (scores, jnp.ones((b,), bool)) if with_stats else scores
     if not pruning:
@@ -426,6 +477,48 @@ def _tiered_scores(q_terms, hot_rank, hot_tfs, tier_of, row_of, tier_docs,
     return _hot_stage_pruned(
         scores, hot_tfs, hot_max_w, q_w, rank, is_hot, hot_matmul,
         hot_cell_fn, prune_k=prune_k, with_stats=with_stats)
+
+
+def _chunk_stream(scores, chunks, safe_q, cold, q_w, *, n_chunks,
+                  num_docs, cold_weight_fn):
+    """The big cold tiers' postings of a block as one stream of chunks.
+
+    Each (query, slot) whose term is streamed (`cold` and count > 0)
+    owns `count` consecutive chunk indices, in row-major slot order;
+    chunk i finds its slot by searchsorted over the running count,
+    gathers its [C] docs and tfs row, and every chunk is weighted and
+    scatter-added into `scores` at (query, doc) in ONE scatter. Work is
+    n_chunks * C lanes instead of B * L * P_t per tier. Lanes past a
+    term's df hold tf 0 and drop like tier padding; chunk indices past
+    the block's need drop whole. A query's updates follow its own slot
+    order whatever else shares the block (the coalesced == solo pin).
+
+    The stream is a lax.cond branch, skipped when the block streams
+    nothing, so a block of hot or small-tier terms moves no chunk
+    lanes whatever its capacity."""
+    b, l = safe_q.shape
+    term = safe_q.reshape(-1)
+    n_s = jnp.where(cold, chunks.count[safe_q], 0).reshape(-1)   # [B*L]
+    ends = jnp.cumsum(n_s)
+    w_s = q_w.reshape(-1)
+
+    def stream(acc):
+        i = jnp.arange(n_chunks, dtype=ends.dtype)
+        slot = jnp.minimum(jnp.searchsorted(ends, i, side="right"),
+                           b * l - 1)
+        live = i < ends[-1]
+        row = jnp.where(live, chunks.row0[term[slot]] + i - ends[slot]
+                        + n_s[slot], 0)
+        docs = chunks.docs[row].astype(jnp.int32)            # [cap, C]
+        tfs = chunks.tfs[row].astype(jnp.float32)
+        keep = (tfs > 0) & live[:, None]
+        w = jnp.where(keep, cold_weight_fn(tfs, docs), 0.0) \
+            * w_s[slot][:, None]
+        return acc.at[(slot // l)[:, None],
+                      jnp.where(keep, docs, num_docs + 1)].add(
+                          w, mode="drop")
+
+    return jax.lax.cond(ends[-1] > 0, stream, lambda acc: acc, scores)
 
 
 def _hot_stage_pruned(partial, hot_tfs, hot_max_w, q_w, rank, is_hot,
@@ -532,7 +625,8 @@ def blockmax_cand_blocks(k: int, num_docs: int, width: int) -> int:
 def _blockmax_topk(q_terms, hot_rank, hot_tfs, tier_of, row_of,
                    tier_docs, tier_tfs, q_weight, hot_blk_bound, *,
                    num_docs, k, width, cand_blocks, hot_weight_fn,
-                   cold_weight_fn, hot_cell_fn):
+                   cold_weight_fn, hot_cell_fn, chunks=None,
+                   n_chunks=None):
     """Shared block-max top-k accumulation (see the section comment).
 
     `hot_blk_bound` f32 [H, nblk] is the per-mode per-block score upper
@@ -577,7 +671,7 @@ def _blockmax_topk(q_terms, hot_rank, hot_tfs, tier_of, row_of,
         q_terms, hot_rank, hot_tfs, tier_of, row_of, tier_docs,
         tier_tfs, q_weight, num_docs=num_docs,
         hot_weight_fn=hot_weight_fn, cold_weight_fn=cold_weight_fn,
-        skip_hot=True)                                       # [B, D+1]
+        skip_hot=True, chunks=chunks, n_chunks=n_chunks)     # [B, D+1]
 
     # running threshold: the k-th best cold partial is a lower bound on
     # the true k-th full score (hot contributions are non-negative).
@@ -657,12 +751,13 @@ def _blockmax_topk(q_terms, hot_rank, hot_tfs, tier_of, row_of,
 
 @partial(profiled_jit, static_argnames=("k", "num_docs", "width",
                                    "cand_blocks", "compat_int_idf",
-                                   "hot_preweighted"))
+                                   "hot_preweighted", "n_chunks"))
 def tfidf_topk_blockmax(
     q_terms, hot_rank, hot_tfs, tier_of, row_of, tier_docs, tier_tfs,
     df, n_scalar, hot_blk_bound, *, num_docs: int, width: int,
     cand_blocks: int, k: int = 10, compat_int_idf: bool = False,
-    hot_preweighted: bool = False,
+    hot_preweighted: bool = False, chunks: ColdChunks | None = None,
+    n_chunks: int | None = None,
 ):
     """Block-max TF-IDF top-k on the tiered layout — the deep-k
     production kernel (see the section comment). Returns
@@ -679,17 +774,18 @@ def tfidf_topk_blockmax(
         hot_weight_fn=_identity_weight if hot_preweighted else _lntf,
         cold_weight_fn=cell_fn,
         hot_cell_fn=((lambda tfs, docs: tfs) if hot_preweighted
-                     else cell_fn))
+                     else cell_fn), chunks=chunks, n_chunks=n_chunks)
 
 
 @partial(profiled_jit, static_argnames=("k", "num_docs", "width",
                                    "cand_blocks", "k1", "b",
-                                   "hot_preweighted"))
+                                   "hot_preweighted", "n_chunks"))
 def bm25_topk_blockmax(
     q_terms, hot_rank, hot_tfs, tier_of, row_of, tier_docs, tier_tfs,
     df, doc_len, n_scalar, hot_blk_bound, *, num_docs: int, width: int,
     cand_blocks: int, k: int = 10, k1: float = 0.9, b: float = 0.4,
-    hot_preweighted: bool = False,
+    hot_preweighted: bool = False, chunks: ColdChunks | None = None,
+    n_chunks: int | None = None,
 ):
     """Block-max Okapi BM25 top-k on the tiered layout (see
     tfidf_topk_blockmax). The per-block bound operand must dominate the
@@ -716,7 +812,7 @@ def bm25_topk_blockmax(
                                                   k1=k1)),
         cold_weight_fn=cell_fn,
         hot_cell_fn=((lambda tfs, docs: tfs) if hot_preweighted
-                     else cell_fn))
+                     else cell_fn), chunks=chunks, n_chunks=n_chunks)
 
 
 # -- pre-weighted hot strips (ISSUE 13) -------------------------------------
@@ -759,8 +855,8 @@ def bm25_strip(hot_tfs: jax.Array, doc_len: jax.Array, n_scalar: jax.Array,
 def _tfidf_tiered_scores(q_terms, hot_rank, hot_tfs, tier_of, row_of,
                          tier_docs, tier_tfs, df, n_scalar, hot_max_tf, *,
                          num_docs, prune_k, compat_int_idf, prune,
-                         skip_hot, hot_only,
-                         hot_preweighted=False) -> jax.Array:
+                         skip_hot, hot_only, hot_preweighted=False,
+                         chunks=None, n_chunks=None) -> jax.Array:
     """[B, D+1] tiered TF-IDF accumulation — shared verbatim between the
     production top-k kernel and the explain score-gather variant
     (prune_k is the production kernel's k; the prune gate and candidate
@@ -787,12 +883,12 @@ def _tfidf_tiered_scores(q_terms, hot_rank, hot_tfs, tier_of, row_of,
         hot_cell_fn=cell_fn if do_prune else None,
         hot_max_w=_lntf(hot_max_tf.astype(jnp.float32)) if do_prune else None,
         prune_k=prune_k if do_prune else None, skip_hot=skip_hot,
-        skip_cold=hot_only)
+        skip_cold=hot_only, chunks=chunks, n_chunks=n_chunks)
 
 
 @partial(profiled_jit, static_argnames=("k", "num_docs", "compat_int_idf",
                                    "prune", "skip_hot", "hot_only",
-                                   "hot_preweighted"))
+                                   "hot_preweighted", "n_chunks"))
 def tfidf_topk_tiered(
     q_terms: jax.Array,        # int32 [B, L]
     hot_rank: jax.Array,       # int32 [V]: row in hot_tfs, or -1 (cold)
@@ -812,6 +908,8 @@ def tfidf_topk_tiered(
     skip_hot: bool = False,
     hot_only: bool = False,
     hot_preweighted: bool = False,
+    chunks: ColdChunks | None = None,
+    n_chunks: int | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """TF-IDF top-k on the tiered sparse layout (search/layout.py): the
     budget-capped hot strip bounds dense memory, geometric tier capacities
@@ -832,23 +930,26 @@ def tfidf_topk_tiered(
     hot strip (the overload ladder's cheapest device level; results are
     partial and must be tagged by the caller). `hot_preweighted=True`
     (static) declares `hot_tfs` ALREADY weighted (lntf_strip) — the hot
-    stage skips its per-dispatch elementwise pass; bit-identical."""
+    stage skips its per-dispatch elementwise pass; bit-identical.
+    `chunks`/`n_chunks` stream the big cold tiers (`_tiered_scores`)."""
     scores = _tfidf_tiered_scores(
         q_terms, hot_rank, hot_tfs, tier_of, row_of, tier_docs, tier_tfs,
         df, n_scalar, hot_max_tf, num_docs=num_docs, prune_k=k,
         compat_int_idf=compat_int_idf, prune=prune, skip_hot=skip_hot,
-        hot_only=hot_only, hot_preweighted=hot_preweighted)
+        hot_only=hot_only, hot_preweighted=hot_preweighted, chunks=chunks,
+        n_chunks=n_chunks)
     return _topk_from_scores(scores, k)
 
 
 @partial(profiled_jit, static_argnames=("num_docs", "prune_k",
                                    "compat_int_idf", "prune", "skip_hot",
-                                   "hot_only"))
+                                   "hot_only", "n_chunks"))
 def tfidf_scores_at_tiered(
     q_terms, hot_rank, hot_tfs, tier_of, row_of, tier_docs, tier_tfs,
     df, n_scalar, cand, hot_max_tf=None, *, num_docs: int,
     prune_k: int = 10, compat_int_idf: bool = False, prune: bool = False,
     skip_hot: bool = False, hot_only: bool = False,
+    chunks: ColdChunks | None = None, n_chunks: int | None = None,
 ) -> jax.Array:
     """Explain debug variant of tfidf_topk_tiered: the same accumulation
     (same static flags, `prune_k` = the production k so the prune gate
@@ -857,13 +958,13 @@ def tfidf_scores_at_tiered(
         q_terms, hot_rank, hot_tfs, tier_of, row_of, tier_docs, tier_tfs,
         df, n_scalar, hot_max_tf, num_docs=num_docs, prune_k=prune_k,
         compat_int_idf=compat_int_idf, prune=prune, skip_hot=skip_hot,
-        hot_only=hot_only)
+        hot_only=hot_only, chunks=chunks, n_chunks=n_chunks)
     return jnp.take_along_axis(scores, cand.astype(jnp.int32), axis=1)
 
 
 @partial(profiled_jit, static_argnames=("k", "num_docs", "k1", "b", "prune",
                                    "skip_hot", "hot_only",
-                                   "hot_preweighted"))
+                                   "hot_preweighted", "n_chunks"))
 def bm25_topk_tiered(
     q_terms: jax.Array,        # int32 [B, L]
     hot_rank: jax.Array,       # int32 [V]
@@ -885,6 +986,8 @@ def bm25_topk_tiered(
     skip_hot: bool = False,
     hot_only: bool = False,
     hot_preweighted: bool = False,
+    chunks: ColdChunks | None = None,
+    n_chunks: int | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Okapi BM25 on the tiered sparse layout — the scorer variant that
     makes BM25 usable past the dense-matrix budget (MS MARCO-scale corpora).
@@ -898,12 +1001,13 @@ def bm25_topk_tiered(
     decreasing in dl_norm, so sat(tf, d) <= sat(max_tf, dl_min) for every
     posting of the row. `hot_preweighted=True` (static) declares
     `hot_tfs` ALREADY saturated (bm25_strip) — bit-identical, minus the
-    per-dispatch elementwise pass."""
+    per-dispatch elementwise pass. `chunks`/`n_chunks` as in
+    tfidf_topk_tiered."""
     scores = _bm25_tiered_scores(
         q_terms, hot_rank, hot_tfs, tier_of, row_of, tier_docs, tier_tfs,
         df, doc_len, n_scalar, hot_max_tf, num_docs=num_docs, prune_k=k,
         k1=k1, b=b, prune=prune, skip_hot=skip_hot, hot_only=hot_only,
-        hot_preweighted=hot_preweighted)
+        hot_preweighted=hot_preweighted, chunks=chunks, n_chunks=n_chunks)
     return _topk_from_scores(scores, k)
 
 
@@ -932,26 +1036,26 @@ bm25_topk_dense_dq = _donated_query_twin(
 tfidf_topk_tiered_dq = _donated_query_twin(
     tfidf_topk_tiered, static_argnames=("k", "num_docs", "compat_int_idf",
                                         "prune", "skip_hot", "hot_only",
-                                        "hot_preweighted"))
+                                        "hot_preweighted", "n_chunks"))
 bm25_topk_tiered_dq = _donated_query_twin(
     bm25_topk_tiered, static_argnames=("k", "num_docs", "k1", "b", "prune",
                                        "skip_hot", "hot_only",
-                                       "hot_preweighted"))
+                                       "hot_preweighted", "n_chunks"))
 tfidf_topk_blockmax_dq = _donated_query_twin(
     tfidf_topk_blockmax, static_argnames=("k", "num_docs", "width",
                                           "cand_blocks", "compat_int_idf",
-                                          "hot_preweighted"))
+                                          "hot_preweighted", "n_chunks"))
 bm25_topk_blockmax_dq = _donated_query_twin(
     bm25_topk_blockmax, static_argnames=("k", "num_docs", "width",
                                          "cand_blocks", "k1", "b",
-                                         "hot_preweighted"))
+                                         "hot_preweighted", "n_chunks"))
 
 
 def _bm25_tiered_scores(q_terms, hot_rank, hot_tfs, tier_of, row_of,
                         tier_docs, tier_tfs, df, doc_len, n_scalar,
                         hot_max_tf, *, num_docs, prune_k, k1, b, prune,
-                        skip_hot, hot_only,
-                        hot_preweighted=False) -> jax.Array:
+                        skip_hot, hot_only, hot_preweighted=False,
+                        chunks=None, n_chunks=None) -> jax.Array:
     """[B, D+1] tiered BM25 accumulation — shared verbatim between the
     production top-k kernel and the explain score-gather variant."""
     n = jnp.asarray(n_scalar, jnp.float32)
@@ -996,16 +1100,18 @@ def _bm25_tiered_scores(q_terms, hot_rank, hot_tfs, tier_of, row_of,
         hot_cell_fn=cell_fn if do_prune else None,
         hot_max_w=hot_max_w,
         prune_k=prune_k if do_prune else None, skip_hot=skip_hot,
-        skip_cold=hot_only)
+        skip_cold=hot_only, chunks=chunks, n_chunks=n_chunks)
 
 
 @partial(profiled_jit, static_argnames=("num_docs", "prune_k", "k1", "b",
-                                   "prune", "skip_hot", "hot_only"))
+                                   "prune", "skip_hot", "hot_only",
+                                   "n_chunks"))
 def bm25_scores_at_tiered(
     q_terms, hot_rank, hot_tfs, tier_of, row_of, tier_docs, tier_tfs,
     df, doc_len, n_scalar, cand, hot_max_tf=None, *, num_docs: int,
     prune_k: int = 10, k1: float = 0.9, b: float = 0.4,
     prune: bool = False, skip_hot: bool = False, hot_only: bool = False,
+    chunks: ColdChunks | None = None, n_chunks: int | None = None,
 ) -> jax.Array:
     """Explain debug variant of bm25_topk_tiered — [B, C] f32 at `cand`
     (see tfidf_scores_at_tiered for the shared-accumulation contract)."""
@@ -1013,7 +1119,7 @@ def bm25_scores_at_tiered(
         q_terms, hot_rank, hot_tfs, tier_of, row_of, tier_docs, tier_tfs,
         df, doc_len, n_scalar, hot_max_tf, num_docs=num_docs,
         prune_k=prune_k, k1=k1, b=b, prune=prune, skip_hot=skip_hot,
-        hot_only=hot_only)
+        hot_only=hot_only, chunks=chunks, n_chunks=n_chunks)
     return jnp.take_along_axis(scores, cand.astype(jnp.int32), axis=1)
 
 
@@ -1109,11 +1215,13 @@ def cosine_scores_at_dense(q_terms, doc_matrix, df, doc_norm, cand_docnos,
                                 cand_docnos, num_docs)
 
 
-@partial(profiled_jit, static_argnames=("k", "num_docs", "hot_preweighted"))
+@partial(profiled_jit, static_argnames=("k", "num_docs", "hot_preweighted",
+                                   "n_chunks"))
 def cosine_rerank_tiered(
     q_terms, hot_rank, hot_tfs, tier_of, row_of, tier_docs, tier_tfs,
     df, doc_norm, n_scalar, cand_docnos, *, num_docs: int, k: int = 10,
-    hot_preweighted: bool = False,
+    hot_preweighted: bool = False, chunks: ColdChunks | None = None,
+    n_chunks: int | None = None,
 ):
     """cosine_rerank_dense on the tiered sparse layout (large corpora).
     The tiered accumulation is doc-axis-wide by construction, so this path
@@ -1123,14 +1231,14 @@ def cosine_rerank_tiered(
     cand_scores = _cosine_tiered_scores(
         q_terms, hot_rank, hot_tfs, tier_of, row_of, tier_docs, tier_tfs,
         df, doc_norm, n_scalar, cand_docnos, num_docs=num_docs,
-        hot_preweighted=hot_preweighted)
+        hot_preweighted=hot_preweighted, chunks=chunks, n_chunks=n_chunks)
     return _topk_over_candidates(cand_scores, cand_docnos, k)
 
 
 def _cosine_tiered_scores(q_terms, hot_rank, hot_tfs, tier_of, row_of,
                           tier_docs, tier_tfs, df, doc_norm, n_scalar,
-                          cand_docnos, *, num_docs,
-                          hot_preweighted=False) -> jax.Array:
+                          cand_docnos, *, num_docs, hot_preweighted=False,
+                          chunks=None, n_chunks=None) -> jax.Array:
     """[B, C] per-candidate tiered cosine scores — shared between the
     production rerank kernel and the explain variant."""
     # lint: invariant-ok (O(V)/O(D) weight-vector prep, fused in-trace;
@@ -1141,7 +1249,8 @@ def _cosine_tiered_scores(q_terms, hot_rank, hot_tfs, tier_of, row_of,
         q_terms, hot_rank, hot_tfs, tier_of, row_of, tier_docs, tier_tfs,
         idf * idf, num_docs=num_docs,
         hot_weight_fn=_identity_weight if hot_preweighted else _lntf,
-        cold_weight_fn=lambda tfs, docs: _lntf(tfs))
+        cold_weight_fn=lambda tfs, docs: _lntf(tfs), chunks=chunks,
+        n_chunks=n_chunks)
     # gather the C candidates FIRST, then normalize: dividing the full
     # [B, D+1] matrix before a [B, C] gather is ~D/C times the divides
     # plus a full-width temporary per rerank block (elementwise divide
@@ -1151,15 +1260,18 @@ def _cosine_tiered_scores(q_terms, hot_rank, hot_tfs, tier_of, row_of,
             / jnp.maximum(doc_norm[cand], 1e-30))
 
 
-@partial(profiled_jit, static_argnames=("num_docs",))
+@partial(profiled_jit, static_argnames=("num_docs", "n_chunks"))
 def cosine_scores_at_tiered(q_terms, hot_rank, hot_tfs, tier_of, row_of,
                             tier_docs, tier_tfs, df, doc_norm, n_scalar,
-                            cand_docnos, *, num_docs: int) -> jax.Array:
+                            cand_docnos, *, num_docs: int,
+                            chunks: ColdChunks | None = None,
+                            n_chunks: int | None = None) -> jax.Array:
     """Explain debug variant of cosine_rerank_tiered: per-candidate
     cosine scores in candidate order ([B, C]), no top-k reorder."""
     return _cosine_tiered_scores(
         q_terms, hot_rank, hot_tfs, tier_of, row_of, tier_docs, tier_tfs,
-        df, doc_norm, n_scalar, cand_docnos, num_docs=num_docs)
+        df, doc_norm, n_scalar, cand_docnos, num_docs=num_docs,
+        chunks=chunks, n_chunks=n_chunks)
 
 
 @partial(profiled_jit, static_argnames=("k", "num_docs", "compat_int_idf"))

@@ -147,6 +147,9 @@ def _scores_at(scorer, q: np.ndarray, docs: np.ndarray, *, scoring: str,
     qd = jnp.asarray(q, jnp.int32)
     cand = jnp.asarray(docs, jnp.int32)
     n = jnp.int32(scorer.meta.num_docs)
+    # the same cold chunk stream the production kernels ran
+    chunk_kw = ({} if hot_only or scorer.layout != "sparse"
+                else scorer._chunk_kwargs(q, True))
     if scorer.layout == "sharded":
         from ..parallel.sharded_tiered import sharded_tiered_scores_at
 
@@ -167,14 +170,14 @@ def _scores_at(scorer, q: np.ndarray, docs: np.ndarray, *, scoring: str,
             qd, scorer.hot_rank, scorer.hot_tfs, scorer.tier_of,
             scorer.row_of, scorer.tier_docs, scorer.tier_tfs, scorer.df,
             scorer.doc_len, n, cand, num_docs=scorer.meta.num_docs,
-            skip_hot=skip_hot, hot_only=hot_only)
+            skip_hot=skip_hot, hot_only=hot_only, **chunk_kw)
     else:
         out = tfidf_scores_at_tiered(
             qd, scorer.hot_rank, scorer.hot_tfs, scorer.tier_of,
             scorer.row_of, scorer.tier_docs, scorer.tier_tfs, scorer.df,
             n, cand, num_docs=scorer.meta.num_docs,
             compat_int_idf=scorer.compat_int_idf, skip_hot=skip_hot,
-            hot_only=hot_only)
+            hot_only=hot_only, **chunk_kw)
     return np.asarray(out)
 
 
@@ -202,7 +205,8 @@ def _cosine_scores_at(scorer, q: np.ndarray, cand: np.ndarray
         out = cosine_scores_at_tiered(
             qd, scorer.hot_rank, scorer.hot_tfs, scorer.tier_of,
             scorer.row_of, scorer.tier_docs, scorer.tier_tfs, scorer.df,
-            scorer._doc_norms(), n, cd, num_docs=scorer.meta.num_docs)
+            scorer._doc_norms(), n, cd, num_docs=scorer.meta.num_docs,
+            **scorer._chunk_kwargs(q, True))
     return np.asarray(out)
 
 
@@ -276,10 +280,8 @@ def _explain_hits(scorer, text, docnos, *, scoring, rerank, hot_only):
             # candidate set exactly as _rerank_primary does, stage 2 reads
             # the cosine scores out of a candidate matrix of the SAME
             # shape — identical traced reduction, identical floats
-            import jax.numpy as jnp
-
-            _, cand_d = scorer._topk_device(jnp.asarray(q, jnp.int32),
-                                            rerank, "bm25")
+            _, cand_d = scorer._topk_device(np.asarray(q, np.int32),
+                                            rerank, "bm25", fit_chunks=True)
             cand_row = np.asarray(cand_d)[:1]            # [1, C]
             cand_full = np.tile(cand_row, (len(qp), 1))
             prefix = _cosine_scores_at(scorer, qp, cand_full)  # [B*, C]

@@ -21,7 +21,14 @@ The reference has no analog (its postings lists are Java ArrayLists read one
 term at a time, IntDocVectorsForwardIndex.java:148-184); this is the
 TPU-native answer to "SequenceFile seek per term" — everything resident,
 shapes static, scoring a query block = one hot einsum + one masked
-gather/scatter-add per tier.
+gather/scatter-add per small tier + one chunk stream for the big tiers.
+
+- **chunk stream**: a tier's stage moves B*L*P_t slots whatever lands in
+  it, so on one device the big tiers (capacity a multiple of COLD_CHUNK)
+  are served as one table of COLD_CHUNK-wide rows instead
+  (`cold_chunk_table`, uploaded at load in their place); a block then
+  moves only the chunks its own terms hold (ops/scoring.py
+  `_chunk_stream`). The sharded layout keeps every tier's own stage.
 """
 
 from __future__ import annotations
@@ -38,6 +45,10 @@ HOT_BUDGET = 500_000_000
 # first tier capacity and geometric growth factor between tiers
 BASE_CAP = 2
 GROWTH = 4
+# width of the big tiers' posting chunks: it divides every capacity
+# BASE_CAP * GROWTH**i from 2048 up, so each such tier splits into rows
+# of whole chunks
+COLD_CHUNK = 2048
 
 
 class TieredPostings(NamedTuple):
@@ -105,6 +116,42 @@ def _densify_hot(rows, docs, vals, *, num_hot: int, width: int,
     strip = jnp.zeros((num_hot, width), dtype)
     return strip.at[rows.astype(jnp.int32), docs.astype(jnp.int32)].set(
         vals.astype(dtype))
+
+
+def cold_chunk_plan(tiers: TieredPostings, df: np.ndarray):
+    """The chunk table's plan: (streamed tier indices, row0, count,
+    chunk), or None when no tier's capacity is a multiple of the chunk
+    width COLD_CHUNK. row0[v] is term v's first row in the concatenated
+    [R, chunk] table and count[v] = ceil(df[v] / chunk) the rows it
+    streams (0 for hot, absent and small-tier terms). Only tier shapes
+    are read, so a serving-cache mmap costs no page-ins here."""
+    chunk = COLD_CHUNK
+    caps = np.array([a.shape[1] for a in tiers.tier_docs], np.int64)
+    streamed = [i for i, c in enumerate(caps) if c % chunk == 0]
+    if not streamed:
+        return None
+    per_row = np.where(caps % chunk == 0, caps // chunk, 0)
+    nrows = np.array([a.shape[0] for a in tiers.tier_docs], np.int64)
+    base = np.concatenate([[0], np.cumsum(nrows * per_row)])[:-1]
+    tof = np.asarray(tiers.tier_of)
+    t = np.where(tof >= 0, tof, 0)
+    on = (tof >= 0) & (per_row[t] > 0)
+    row0 = np.where(on, base[t] + np.asarray(tiers.row_of) * per_row[t], 0)
+    count = np.where(on, np.minimum(-(-np.asarray(df, np.int64) // chunk),
+                                    per_row[t]), 0)
+    return streamed, row0.astype(np.int32), count.astype(np.int32), chunk
+
+
+def cold_chunk_table(tiers: TieredPostings, plan) -> tuple:
+    """The streamed tiers as host (docs, tfs) tables: each [V_t, P_t]
+    tier viewed as [V_t * P_t / chunk, chunk] rows (a free reshape) and
+    concatenated in plan order. The tiers share one dtype (`_slim` sizes
+    them on global bounds), so this is a copy, not a conversion. The
+    Scorer uploads these in place of the streamed tiers."""
+    streamed, _, _, chunk = plan
+    return tuple(np.concatenate([np.asarray(arrs[i]).reshape(-1, chunk)
+                                 for i in streamed])
+                 for arrs in (tiers.tier_docs, tiers.tier_tfs))
 
 
 def _slim(a: np.ndarray, hi: int) -> np.ndarray:

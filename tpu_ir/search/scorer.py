@@ -30,10 +30,10 @@ from ..obs import trace as obs_trace
 from ..collection import KGRAM_SEP, DocnoMapping, Vocab, kgram_terms
 from ..index import format as fmt
 from ..ops import bm25_topk_dense, dense_doc_matrix, tfidf_topk_dense
-from ..ops.scoring import dense_tf_matrix
+from ..ops.scoring import ColdChunks, dense_tf_matrix
 from ..utils.report import recovery_counters
 from ..utils.transfer import issue_host_copies, stream_to_device
-from .layout import build_tiered_layout
+from .layout import build_tiered_layout, cold_chunk_plan, cold_chunk_table
 
 # dense [V, D+1] matrix budget in elements (f32); above this use sparse CSR
 DENSE_BUDGET = 500_000_000
@@ -396,12 +396,29 @@ class Scorer:
             self.tier_of = stream_to_device(tiers.tier_of,
                                             label="tier_of")
             self.row_of = stream_to_device(tiers.row_of, label="row_of")
-            self.tier_docs = tuple(
-                stream_to_device(a, label=f"tier_docs_{i}")
-                for i, a in enumerate(tiers.tier_docs))
-            self.tier_tfs = tuple(
-                stream_to_device(a, label=f"tier_tfs_{i}")
-                for i, a in enumerate(tiers.tier_tfs))
+            # the big tiers are uploaded as one table of posting chunks
+            # the kernels stream per block (layout.cold_chunk_table);
+            # in their tier slots stay [0, P_t] placeholders, which
+            # keep the tier indices and capacities and hold no bytes
+            plan = cold_chunk_plan(tiers, np.asarray(df))
+            streamed = () if plan is None else plan[0]
+            self.tier_docs, self.tier_tfs = (
+                tuple(jnp.zeros((0, a.shape[1]), a.dtype) if i in streamed
+                      else stream_to_device(a, label=f"{name}_{i}")
+                      for i, a in enumerate(arrs))
+                for name, arrs in (("tier_docs", tiers.tier_docs),
+                                   ("tier_tfs", tiers.tier_tfs)))
+            if plan is not None:
+                with obs_trace("load.layout", layout="cold_chunks"):
+                    docs, tfs = cold_chunk_table(tiers, plan)
+                self.cold_chunks = ColdChunks(
+                    stream_to_device(docs, label="cold_chunk_docs"),
+                    stream_to_device(tfs, label="cold_chunk_tfs"),
+                    jnp.asarray(plan[1]), jnp.asarray(plan[2]))
+                self._chunk_count_host = plan[2]
+                # rows a term of the widest streamed tier can hold
+                self._chunk_widest = max(tiers.tier_docs[i].shape[1]
+                                         for i in streamed) // plan[3]
 
     # -- loading -----------------------------------------------------------
 
@@ -1090,6 +1107,9 @@ class Scorer:
 
     # max elements of the [B_block, D+1] score accumulator per dispatch
     SCORE_BUDGET = 250_000_000
+    # the big cold tiers as one chunk table (ops.scoring.ColdChunks),
+    # built at load on the tiered layout when a tier is wide enough
+    cold_chunks = None
     # minimum hot-free group size worth its own (matmul-skipping)
     # dispatch when the batch is mixed
     MIN_SKIP_GROUP = 32
@@ -1285,11 +1305,13 @@ class Scorer:
                                       donate=donate)
         if hot_only or self.layout != "sparse" or not self.prune:
             # hot_only: no MaxScore scheduling — the cold stages it
-            # schedules around are statically absent
+            # schedules around are statically absent. Rung-padded
+            # batches keep the worst-case chunk capacity, so their
+            # shapes stay a function of the rung
             return self._blocked_dispatch(
-                block, lambda qb: self._topk_device(qb, k, scoring,
-                                                    hot_only=hot_only,
-                                                    donate=donate),
+                block, lambda qb: self._topk_device(
+                    qb, k, scoring, hot_only=hot_only, donate=donate,
+                    fit_chunks=uniform is None),
                 (q, -1))
         with obs_trace("search.schedule", queries=len(q)):
             has_hot, n_free, mode = self._skip_plan(q)
@@ -1300,14 +1322,16 @@ class Scorer:
                 block,
                 lambda qb: self._topk_device(qb, k, scoring,
                                              skip_hot=True,
-                                             donate=donate), (q, -1))
+                                             donate=donate,
+                                             fit_chunks=True), (q, -1))
         if mode == "all_full":
             # too few hot-free queries to pay an extra dispatch for
             self._ledger_skip_plan(len(q), n_free, 0,
                                    -(-len(q) // block))
             return self._blocked_dispatch(
                 block, lambda qb: self._topk_device(qb, k, scoring,
-                                                    donate=donate),
+                                                    donate=donate,
+                                                    fit_chunks=True),
                 (q, -1))
         self._ledger_skip_plan(len(q), n_free, -(-n_free // block),
                                -(-(len(q) - n_free) // block))
@@ -1318,10 +1342,11 @@ class Scorer:
         s1, d1 = self._group_dispatch(qs[:n_free], block,
                                       lambda qb: self._topk_device(
                                           qb, k, scoring, skip_hot=True,
-                                          donate=donate))
+                                          donate=donate, fit_chunks=True))
         s2, d2 = self._group_dispatch(qs[n_free:], block,
                                       lambda qb: self._topk_device(
-                                          qb, k, scoring, donate=donate))
+                                          qb, k, scoring, donate=donate,
+                                          fit_chunks=True))
         return (np.concatenate([s1, s2])[inv],
                 np.concatenate([d1, d2])[inv])
 
@@ -1784,7 +1809,7 @@ class Scorer:
 
     def _topk_device(self, q_terms: np.ndarray, k: int, scoring: str,
                      skip_hot: bool = False, hot_only: bool = False,
-                     donate: bool = False):
+                     donate: bool = False, fit_chunks: bool = False):
         """Dispatch one query block; returns device arrays without
         waiting. `skip_hot` statically omits the tiered hot-strip stage
         (exact only for blocks the scheduler certified hot-free);
@@ -1792,6 +1817,9 @@ class Scorer:
         ladder's cheapest level — partial scores, results must be
         tagged). On the dense layout hot_only is a no-op: there is no
         cheaper stage to keep, so it serves the full matrix.
+        `fit_chunks` sizes the cold chunk stream to this block's own
+        postings (a compile per capacity bucket); without it the shape
+        is a function of the block's shape alone (`_chunk_kwargs`).
 
         The "kernel" span times the jit call + injected hangs for THIS
         block (the dispatch is async on real hardware — completion cost
@@ -1802,15 +1830,52 @@ class Scorer:
             return self._topk_device_raw(q_terms, k, scoring,
                                          skip_hot=skip_hot,
                                          hot_only=hot_only,
-                                         donate=donate)
+                                         donate=donate,
+                                         fit_chunks=fit_chunks)
+
+    def _chunk_kwargs(self, q_terms: np.ndarray, fit: bool) -> dict:
+        """The cold chunk stream's kernel arguments for one tiered
+        dispatch of host query ids `q_terms`, {} without a chunk table.
+
+        `fit=True` passes the static capacity this block needs (its
+        streamed terms' ceil(df / C) summed, ops.scoring.chunk_bucket).
+        `fit=False` passes the worst case, every slot on the widest
+        streamed tier, so callers whose compiled set must stay closed
+        (the coalescer's rungs and precompile) mint no content-dependent
+        shape. Each dispatch that streams counts in cold.chunk_postings
+        (real postings streamed) and cold.chunk_slots (capacity x C
+        lanes dispatched)."""
+        if self.cold_chunks is None:
+            return {}
+        from ..ops.scoring import chunk_bucket
+
+        q = np.asarray(q_terms)
+        count = self._chunk_count_host
+        valid = (q >= 0) & (q < len(count))
+        safe = np.where(valid, q, 0)
+        n = np.where(valid, count[safe], 0)
+        need = int(n.sum())
+        worst = q.size * self._chunk_widest
+        cap = min(chunk_bucket(need), worst) if fit else worst
+        if need:   # a block that streams nothing runs no stream
+            from ..obs import get_registry
+
+            reg = get_registry()
+            reg.incr("cold.chunk_postings",
+                     int(self._df_host()[safe][n > 0].sum()))
+            reg.incr("cold.chunk_slots",
+                     cap * int(self.cold_chunks.docs.shape[1]))
+        return {"chunks": self.cold_chunks, "n_chunks": cap}
 
     def _topk_device_raw(self, q_terms: np.ndarray, k: int, scoring: str,
                          skip_hot: bool = False, hot_only: bool = False,
-                         donate: bool = False):
+                         donate: bool = False, fit_chunks: bool = False):
         faults.maybe_hang("score.hang")
         if faults.should_fire("score.device_loss") is not None:
             raise faults.DeviceLoss("injected device loss")
         donate = donate and _donation_enabled() and self.layout != "sharded"
+        cold = ({} if hot_only or self.layout != "sparse"
+                else self._chunk_kwargs(q_terms, fit_chunks))
         q = jnp.asarray(q_terms)
         n = jnp.int32(self.meta.num_docs)
         if self.layout == "sharded":
@@ -1856,7 +1921,7 @@ class Scorer:
                     self.row_of, self.tier_docs, self.tier_tfs, self.df,
                     self.doc_len, n, bound, num_docs=self.meta.num_docs,
                     width=width, cand_blocks=cand, k=k, k1=_k1, b=_b,
-                    hot_preweighted=ws is not None)
+                    hot_preweighted=ws is not None, **cold)
                 self._note_blockmax_stats(stats)
             else:
                 from ..ops.scoring import bm25_topk_tiered, bm25_topk_tiered_dq
@@ -1874,7 +1939,7 @@ class Scorer:
                     self.row_of, self.tier_docs, self.tier_tfs, self.df,
                     self.doc_len, n, num_docs=self.meta.num_docs, k=k,
                     k1=_k1, b=_b, skip_hot=skip_hot, hot_only=hot_only,
-                    hot_preweighted=ws is not None)
+                    hot_preweighted=ws is not None, **cold)
         elif self.layout == "dense":
             from ..ops.scoring import tfidf_topk_dense_dq
 
@@ -1898,7 +1963,7 @@ class Scorer:
                 bound, num_docs=self.meta.num_docs, width=width,
                 cand_blocks=cand, k=k,
                 compat_int_idf=self.compat_int_idf,
-                hot_preweighted=ws is not None)
+                hot_preweighted=ws is not None, **cold)
             self._note_blockmax_stats(stats)
         else:
             from ..ops.scoring import tfidf_topk_tiered, tfidf_topk_tiered_dq
@@ -1911,7 +1976,7 @@ class Scorer:
                 self.row_of, self.tier_docs, self.tier_tfs, self.df, n,
                 num_docs=self.meta.num_docs, k=k,
                 compat_int_idf=self.compat_int_idf, skip_hot=skip_hot,
-                hot_only=hot_only, hot_preweighted=ws is not None)
+                hot_only=hot_only, hot_preweighted=ws is not None, **cold)
         return s, d
 
     def _ensure_tf_matrix(self):
@@ -2141,7 +2206,8 @@ class Scorer:
         # budget dominates the block size.
         def dispatch(q):
             qd = jnp.asarray(q)
-            _, cand_d = self._topk_device(qd, candidates, "bm25")
+            _, cand_d = self._topk_device(q, candidates, "bm25",
+                                          fit_chunks=True)
             if self.layout == "dense":
                 return cosine_rerank_dense(
                     qd, self.doc_matrix, self.df, norms, cand_d, n, k=k)
@@ -2153,7 +2219,8 @@ class Scorer:
                 ws if ws is not None else self.hot_tfs, self.tier_of,
                 self.row_of, self.tier_docs, self.tier_tfs, self.df,
                 norms, n, cand_d, num_docs=self.meta.num_docs, k=k,
-                hot_preweighted=ws is not None)
+                hot_preweighted=ws is not None,
+                **self._chunk_kwargs(q, True))
 
         return self._blocked_dispatch(
             self._block_size(), dispatch,
