@@ -93,12 +93,14 @@ SERVICE_LEVELS = ("full", "no_rerank", "hot_only", "shed")
 # cache-miss only) complete the cold load, and `load` spans the whole
 # Scorer.load.
 LOAD_STAGES = ("load.verify", "load.read", "load.assemble", "load.layout",
-               "load.cache_write", "load.h2d")
+               "load.cache_write", "load.h2d", "load.positions")
 
-# Scorer query-path spans, one per batch: the whole plain-query search
-# (analysis, schedule, dispatch, result assembly, query log), its query
-# analysis, and the MaxScore hot/cold partition.
-SEARCH_STAGES = ("search", "search.analyze", "search.schedule")
+# Scorer query-path spans, one per batch: the whole search (analysis,
+# schedule, dispatch, result assembly, query log), its query analysis,
+# the MaxScore hot/cold partition, and the device phrase path's match of
+# one block (ops/phrase.py phrase_match, up to its candidate count).
+SEARCH_STAGES = ("search", "search.analyze", "search.schedule",
+                 "phrase.match")
 
 # Recovery-event counter names (the `recovery.` namespace, incremented
 # via utils/report.recovery_counters()). Declared so the lint contract
@@ -234,6 +236,13 @@ PRUNE_COUNTER_NAMES = (
 # stream's fill.
 COLD_CHUNK_COUNTER_NAMES = ("cold.chunk_postings", "cold.chunk_slots")
 
+# Device phrase path (ops/phrase.py, Scorer._search_phrases_device):
+# quoted queries dispatched, anchor positions streamed, anchor lanes
+# dispatched (n_chunks x chunk width; positions / slots is the fill),
+# and matched (span, doc) candidates.
+PHRASE_COUNTER_NAMES = ("phrase.queries", "phrase.positions",
+                        "phrase.slots", "phrase.candidates")
+
 # Generation-keyed exact-hit result cache (ISSUE 15,
 # serving/result_cache.py): hit/miss the lookup verdicts (hit_fraction =
 # hit / (hit + miss)), evict the LRU displacements under the bounded
@@ -315,7 +324,7 @@ DECLARED_COUNTERS = tuple(f"fault.{s}" for s in FAULT_SITES) + (
 ) + (COMPILE_COUNTER_NAMES + QUERYLOG_COUNTER_NAMES + BATCH_COUNTER_NAMES
      + ROUTER_COUNTER_NAMES + BUILD_COUNTER_NAMES + INGEST_COUNTER_NAMES
      + PRUNE_COUNTER_NAMES + COLD_CHUNK_COUNTER_NAMES
-     + CACHE_COUNTER_NAMES + SCALE_COUNTER_NAMES
+     + PHRASE_COUNTER_NAMES + CACHE_COUNTER_NAMES + SCALE_COUNTER_NAMES
      + DISTTRACE_COUNTER_NAMES + TIMESERIES_COUNTER_NAMES
      + COMPRESS_COUNTER_NAMES)
 # "request" (the root span, all levels pooled) rides alongside the

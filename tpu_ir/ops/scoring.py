@@ -160,6 +160,7 @@ def bm25_idf_weights(df: jax.Array, n: jax.Array) -> jax.Array:
     with inconsistent df==0 masking (the dense copy relied on zero
     tf-matrix rows, a subtlety each copy had to re-reason about)."""
     dff = df.astype(jnp.float32)
+    # lint: invariant-ok (a scalar cast)
     n_f = jnp.asarray(n, jnp.float32)
     # log1p, not log(1 + x): for df near N, x ~ 0.5/N and 1 + x would
     # round away most of it (see idf_weights)

@@ -33,6 +33,7 @@ gather/scatter-add per small tier + one chunk stream for the big tiers.
 
 from __future__ import annotations
 
+import os
 from functools import partial
 from typing import NamedTuple
 
@@ -152,6 +153,92 @@ def cold_chunk_table(tiers: TieredPostings, plan) -> tuple:
     return tuple(np.concatenate([np.asarray(arrs[i]).reshape(-1, chunk)
                                  for i in streamed])
                  for arrs in (tiers.tier_docs, tiers.tier_tfs))
+
+
+def position_table(index_dir: str, meta, doc_len: np.ndarray) -> tuple:
+    """The positional index as the host arrays of ops/phrase.py
+    PositionTable (docs, at, start, cf, fwd, doc_start): each part's
+    delta-coded position runs (index/positions.py) decoded to absolute
+    positions and reordered from the pair order (tf descending) to
+    (docno, position) order, term by term, the terms concatenated by id
+    (`start[t]` term t's first index, `cf[t]` its position count); and
+    the same positions as one doc-major token stream `fwd` of rows of
+    PHRASE_SLICE, doc d from row doc_start[d] / PHRASE_SLICE with at
+    least PHRASE_GAP empty slots (-1) after its doc_len[d] tokens.
+    `at` is each position's index in that stream. docs/at are laid out
+    in rows of PHRASE_CHUNK with at least one row past the last
+    position, so the two rows an anchor chunk spans always exist.
+    Shards are decoded concurrently, each into its own terms' slices."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    from ..index import format as fmt
+    from ..index.positions import positions_name, realign_runs
+    from ..ops.phrase import PHRASE_CHUNK, PHRASE_GAP, PHRASE_SLICE
+
+    def read(s: int):
+        z = fmt.load_shard(index_dir, s, mmap=True)
+        with np.load(os.path.join(index_dir, positions_name(s))) as pz:
+            return z, pz["pos_indptr"], pz["pos_delta"]
+
+    threads = max(1, min(fmt.load_threads(), meta.num_shards))
+    with ThreadPoolExecutor(max_workers=threads) as ex:
+        shards = list(ex.map(read, range(meta.num_shards)))
+    cf = np.zeros(meta.vocab_size, np.int64)
+    for z, indptr, _ in shards:
+        row_term = np.repeat(np.asarray(z["term_ids"], np.int64),
+                             np.diff(z["indptr"]))
+        cf += np.bincount(row_term, weights=np.diff(indptr),
+                          minlength=len(cf)).astype(np.int64)
+    start = np.concatenate([[0], np.cumsum(cf)])
+    doc_len = np.asarray(doc_len, np.int64)
+    doc_start = PHRASE_SLICE * np.concatenate([[0], np.cumsum(
+        -(-(doc_len + PHRASE_GAP) // PHRASE_SLICE))])
+    if max(start[-1], doc_start[-1]) >= 2**31:
+        raise ValueError("the positions overflow the int32 offsets of "
+                         "the device position table")
+    n_rows = int(start[-1]) // PHRASE_CHUNK + 2
+    docs = np.zeros(n_rows * PHRASE_CHUNK, np.int32)
+    at = np.zeros(n_rows * PHRASE_CHUNK, np.int32)
+    fwd = np.full(int(doc_start[-1]), -1, np.int32)
+
+    def place(shard) -> None:
+        z, indptr, delta = shard
+        run_len = np.diff(indptr)
+        if not len(run_len):
+            return
+        row_term = np.repeat(np.asarray(z["term_ids"], np.int64),
+                             np.diff(z["indptr"]))
+        row_doc = np.asarray(z["pair_doc"], np.int64)
+        # absolute positions: a segmented cumsum of each run's deltas
+        c = np.cumsum(delta, dtype=np.int64)
+        first = indptr[:-1]
+        pos = c - np.repeat(c[first] - delta[first], run_len)
+        order = np.argsort(row_term * (meta.num_docs + 1) + row_doc,
+                           kind="stable")
+        _, gather = realign_runs(first[order], run_len[order])
+        terms = row_term[order]
+        # a term's rows are contiguous in `order`, so its offset within
+        # the shard's run is the cumsum up to its first row
+        tstart = np.flatnonzero(np.diff(terms, prepend=-1))
+        base = np.repeat(np.concatenate([[0], np.cumsum(run_len[order])])[
+            tstart], np.diff(np.append(tstart, len(terms))))
+        dest = (np.repeat(start[terms] - base, run_len[order])
+                + np.arange(int(run_len.sum())))
+        d = np.repeat(row_doc[order], run_len[order])
+        p = pos[gather]
+        if (p >= doc_len[d]).any():
+            raise ValueError("a position lies past its document's length")
+        docs[dest] = d
+        at[dest] = doc_start[d] + p
+        fwd[doc_start[d] + p] = np.repeat(terms.astype(np.int32),
+                                          run_len[order])
+
+    with ThreadPoolExecutor(max_workers=threads) as ex:
+        list(ex.map(place, shards))
+    return (docs.reshape(n_rows, PHRASE_CHUNK),
+            at.reshape(n_rows, PHRASE_CHUNK), start[:-1].astype(np.int32),
+            cf.astype(np.int32), fwd.reshape(-1, PHRASE_SLICE),
+            doc_start.astype(np.int32))
 
 
 def _slim(a: np.ndarray, hi: int) -> np.ndarray:

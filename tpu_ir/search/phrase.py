@@ -13,11 +13,14 @@ With positions in the index, two beyond-parity capabilities open up:
 - a proximity feature for the two-stage rerank: candidates where query
   terms sit close together get a multiplicative boost.
 
-These run HOST-side by design: a phrase touches a handful of dictionary
-seeks + position runs (KB of data), which would not amortize a device
-dispatch and its round trip — the same reasoning that keeps the
-dictionary seek path (index/dictionary.py) on host while batch
-scoring owns the device.
+Which path runs where: `Scorer.search_batch` matches and scores the
+quoted queries of a call on the device, together, over the position
+table uploaded at load (ops/phrase.py, Scorer._search_phrases_device).
+The host pipeline here (`PhraseIndex.match_window` with
+`score_docs_host`, or `cosine_score_host` and `proximity_bonus` for the
+rerank) answers phrases when the caller passes `force_host` (the
+circuit breaker), `rerank` or `prox`, and serves `--prox` reranking of
+plain queries. `K1` and `B` are the BM25 constants every path shares.
 """
 
 from __future__ import annotations

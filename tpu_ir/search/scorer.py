@@ -90,6 +90,12 @@ def _donation_enabled() -> bool:
     return mode == "1"
 
 
+def _pow2(n: int, floor: int = 8) -> int:
+    """`n` rounded up to a power of two, at least `floor`: the bucketed
+    static sizes of the phrase path's rows and queries."""
+    return max(floor, 1 << max(int(n) - 1, 0).bit_length())
+
+
 class SearchResult(list):
     """List of (docno, score) or (docid, score) tuples for one query.
 
@@ -431,18 +437,22 @@ class Scorer:
         """Load an index dir for serving, the whole load one `load` span
         with its stages as children: load.read (side artifacts and part
         shards, load.verify inside), load.assemble, load.layout,
-        load.cache_write (cache miss only) and load.h2d."""
+        load.cache_write (cache miss only), load.h2d, and for an index
+        with positions load.positions (the device position table of the
+        phrase path; an index without positions uploads none)."""
         if layout not in ("auto", "dense", "sparse", "sharded"):
             # fail before any IO — a typo'd layout should not cost the
             # minutes-long shard read + CSR assembly of a large index
             raise ValueError(f"unknown layout {layout!r}; expected "
                              "'auto', 'dense', 'sparse' or 'sharded'")
         with obs_trace("load", layout=layout):
-            return cls._load(index_dir, layout=layout,
-                             compat_int_idf=compat_int_idf, prune=prune,
-                             deadline_s=deadline_s,
-                             verify_integrity=verify_integrity,
-                             doc_range=doc_range)
+            scorer = cls._load(index_dir, layout=layout,
+                               compat_int_idf=compat_int_idf, prune=prune,
+                               deadline_s=deadline_s,
+                               verify_integrity=verify_integrity,
+                               doc_range=doc_range)
+            scorer._position_table()
+            return scorer
 
     @classmethod
     def _load(cls, index_dir: str, *, layout: str, compat_int_idf: bool,
@@ -2243,7 +2253,11 @@ class Scorer:
         `prox=True` adds the positions-based proximity boost to the rerank
         (search/phrase.py). Queries containing double-quoted spans run as
         phrase queries (ordered window, `phrase_slop` extra token gaps) —
-        both need a format-v2 index built with positions.
+        both need a format-v2 index built with positions. The quoted
+        queries of a call are matched and scored on the device together
+        (`_search_phrases_device`, ops/phrase.py), in blocks; with
+        `force_host` (the circuit breaker), `rerank` or `prox` they run
+        the host pipeline instead (`_search_phrase`, search/phrase.py).
 
         Serving knobs (tpu_ir.serving.ServingFrontend is the intended
         caller): `deadline_s` bounds this batch's device dispatch,
@@ -2252,13 +2266,14 @@ class Scorer:
         tier on tiered/sharded layouts. Each SearchResult's `degraded`
         flag is tagged from THIS request's outcome (thread-safe — the
         tagged dispatch return is the only degradation source). Phrase
-        queries already run on the host and ignore the device knobs.
+        queries take `force_host` only: their device blocks have no
+        deadline and no hot-only level.
 
         `explain_k=N` attaches a per-term score decomposition for each
         query's top-N hits (SearchResult.explain; search/explain.py) —
         exact kernel floats, extra debug dispatches, so a forensics
         knob, not a default. Degraded responses and phrase/prox results
-        (host-scored) carry explain=None.
+        carry explain=None.
 
         Batch-entry knobs (ISSUE 9 — the coalescing frontend is the
         intended caller; per-request semantics are tagged PER SLOT, not
@@ -2273,7 +2288,7 @@ class Scorer:
         uses the donated-query kernel twins on the plain topk path;
         `slot_meta[i]` merges per-slot fields (service level, queue
         wait, occupancy) into query i's querylog entry. Phrase queries
-        cannot ride a padded batch (they score on the host)."""
+        cannot ride a padded batch (they have their own dispatch)."""
         if prox and not rerank:
             raise ValueError("the proximity boost is stage 3 of the "
                              "two-stage rerank; pass rerank=N (--rerank) "
@@ -2301,12 +2316,20 @@ class Scorer:
                     explain_ks=explain_ks, pad_to=pad_to,
                     width_floor=width_floor, rung_ladder=rung_ladder,
                     donate_queries=donate_queries, slot_meta=slot_meta)
+        quoted = [t for t in texts if '"' in t]
+        if quoted and not (force_host or rerank or prox):
+            with obs_trace("search", queries=len(quoted), phrase=True):
+                quoted_out = iter(self._search_phrases_device(
+                    quoted, k=k, scoring=scoring, slop=phrase_slop,
+                    return_docids=return_docids))
+        else:
+            quoted_out = (self._search_phrase(
+                t, k=k, scoring=scoring, slop=phrase_slop,
+                return_docids=return_docids, rerank=rerank, prox=prox)
+                for t in quoted)
         plain_iter = iter(plain_out)
-        return [self._search_phrase(t, k=k, scoring=scoring,
-                                    slop=phrase_slop,
-                                    return_docids=return_docids,
-                                    rerank=rerank, prox=prox)
-                if '"' in t else next(plain_iter) for t in texts]
+        return [next(quoted_out) if '"' in t else next(plain_iter)
+                for t in texts]
 
     def _search_batch_plain(
         self, texts: Sequence[str], *, k: int, scoring: str,
@@ -2490,6 +2513,34 @@ class Scorer:
             self._phrase = PhraseIndex(self._index_dir, meta=self.meta)
         return self._phrase
 
+    # the device phrase path (ops/phrase.py): the position table, built
+    # at load for an index with positions, and the anchor lanes one
+    # dispatch block may stream (blocks split where a call needs more)
+    positions = None
+    PHRASE_LANE_BUDGET = 1 << 23
+
+    def _position_table(self):
+        """The device position table of the phrase path, or None for an
+        index without positions (nothing is built or uploaded then).
+        Scorer.load builds it as its `load.positions` stage; a Scorer
+        constructed over an index dir builds it on first use."""
+        if (self.positions is None and self.meta.has_positions
+                and self._index_dir is not None):
+            from ..ops.phrase import PositionTable
+            from .layout import position_table
+
+            with obs_trace("load.positions"):
+                host = position_table(self._index_dir, self.meta,
+                                      np.asarray(self.doc_len))
+                table = PositionTable(*(
+                    stream_to_device(a, label=f"positions_{n}")
+                    for a, n in zip(host, PositionTable._fields)))
+            with self._lazy_lock:
+                if self.positions is None:
+                    self._pos_cf = host[3]
+                    self.positions = table
+        return self.positions
+
     def _query_term_sequence(self, text: str) -> list[str]:
         """The query's analyzed index-term sequence (k-grams composed) —
         the coordinate system position runs are stored in."""
@@ -2498,10 +2549,11 @@ class Scorer:
     def _search_phrase(self, text: str, *, k: int, scoring: str, slop: int,
                        return_docids: bool, rerank: int | None = None,
                        prox: bool = False) -> SearchResult:
-        """One phrase query: every quoted span must match as an ordered
-        window; matching docs are ranked by the standard scoring model
-        over ALL query terms (host — a phrase-filtered candidate set is
-        KB-scale and cannot amortize a device dispatch). `rerank`/`prox`
+        """One phrase query on the host pipeline (search/phrase.py), the
+        path of `force_host`, `rerank` and `prox`: every quoted span must
+        match as an ordered window; matching docs are ranked by the
+        standard scoring model over ALL query terms, as on the device
+        path (`_search_phrases_device`). `rerank`/`prox`
         compose exactly as on the plain path: BM25 selects the top-N
         matched docs, cosine TF-IDF rescores them, proximity boosts the
         top of that — so a batch mixing quoted and plain queries runs ONE
@@ -2578,6 +2630,163 @@ class Scorer:
             res.append((key, float(scores[i])))
         return self._querylog_phrase(text, res, t0, k=k, scoring=scoring,
                                      rerank=rerank)
+
+    def _search_phrases_device(self, texts: Sequence[str], *, k: int,
+                               scoring: str, slop: int,
+                               return_docids: bool) -> list[SearchResult]:
+        """Quoted queries answered on the device, with `_search_phrase`'s
+        semantics: every quoted span must match as an ordered window (at
+        `slop` extra gaps), and the matched docs are ranked by `scoring`
+        over all query terms, zero scores kept, ties by docno. Each span
+        is a row of ops/phrase.py `phrase_match` (its rarest term's
+        positions streamed as anchors); queries are cut into blocks of
+        at most PHRASE_LANE_BUDGET anchor lanes (`_phrase_block`), all
+        in one `dispatch` span: per block one `phrase.match` span (the
+        match, then a `dispatch.device` sync for its candidate and row
+        counts, which size the scoring stage) and one `kernel` span
+        (`phrase_topk` over the candidates), then one `dispatch.device`
+        for the answers. A span with a term the
+        index lacks matches nothing and is not dispatched. Counters:
+        phrase.queries (queries dispatched), phrase.positions (anchor
+        positions streamed), phrase.slots (anchor lanes dispatched) and
+        phrase.candidates (matched (span, doc) pairs)."""
+        from ..ops.phrase import PHRASE_CHUNK
+        from .phrase import split_phrases
+
+        t0 = time.perf_counter()
+        out: list = [None] * len(texts)
+        todo = []   # (index, [span term ids], [query term ids])
+        with obs_trace("search.analyze", queries=len(texts)):
+            for i, text in enumerate(texts):
+                spans = [self._query_term_sequence(p)
+                         for p in split_phrases(text)[1]]
+                spans = [toks for toks in spans if toks]
+                if not spans:
+                    # a stray or empty quote: a plain query on any index
+                    out[i] = self._search_batch_plain(
+                        [text.replace('"', ' ')], k=k, scoring=scoring,
+                        return_docids=return_docids, rerank=None,
+                        prox=False)[0]
+                    continue
+                if self._position_table() is None:
+                    self._phrase_index()   # raises: no positions
+                ids = [[self.vocab.id_or(t) for t in toks]
+                       for toks in spans]
+                if any(t < 0 or self._pos_cf[t] == 0
+                       for span in ids for t in span):
+                    out[i] = self._querylog_phrase(
+                        text, SearchResult(), t0, k=k, scoring=scoring,
+                        rerank=None)
+                    continue
+                todo.append((i, ids, [
+                    self.vocab.id_or(t) for t in
+                    self._query_term_sequence(text.replace('"', ' '))]))
+        # blocks of whole queries within the anchor-lane budget
+        blocks, cur, lanes = [], [], 0
+        for q in todo:
+            need = sum(self._anchor_chunks(s) for s in q[1]) * PHRASE_CHUNK
+            if cur and lanes + need > self.PHRASE_LANE_BUDGET:
+                blocks.append(cur)
+                cur, lanes = [], 0
+            cur.append(q)
+            lanes += need
+        if cur:
+            blocks.append(cur)
+        with obs_trace("dispatch", label="phrase dispatch",
+                       blocks=len(blocks)):
+            pending = [self._phrase_block(b, k=k, scoring=scoring,
+                                          slop=slop) for b in blocks]
+            with obs_trace("dispatch.device", blocks=len(pending)):
+                fetched = [(np.asarray(s), np.asarray(d))
+                           for s, d in pending]
+        for block, (scores, docnos) in zip(blocks, fetched):
+            for qi, (i, _, _) in enumerate(block):
+                res = SearchResult()
+                for sc, dn in zip(scores[qi], docnos[qi]):
+                    if dn > 0:  # zero-score matches are kept
+                        key = (self.mapping.get_docid(int(dn))
+                               if return_docids else int(dn))
+                        res.append((key, float(sc)))
+                out[i] = self._querylog_phrase(texts[i], res, t0, k=k,
+                                               scoring=scoring,
+                                               rerank=None)
+        return out
+
+    def _anchor_chunks(self, span) -> int:
+        """Chunks a span's row streams: its rarest term's positions."""
+        from ..ops.phrase import PHRASE_CHUNK
+
+        return -(-int(self._pos_cf[span].min()) // PHRASE_CHUNK)
+
+    def _phrase_block(self, block, *, k: int, scoring: str, slop: int):
+        """Dispatch one block of `_search_phrases_device`'s queries
+        [(index, [span term ids], [query term ids])]: the match, one
+        sync for its per-span candidate counts and the token-stream rows
+        of the matched docs (they size the scoring stage), then the
+        scoring and top-k. Returns the (scores, docnos) device
+        arrays without waiting for them."""
+        import jax
+
+        from ..obs import get_registry
+        from ..ops.phrase import PHRASE_CHUNK, phrase_match, phrase_topk
+        from ..ops.scoring import chunk_bucket
+        from .phrase import B as _b, K1 as _k1
+
+        rows = [(qi, s) for qi, (_, spans, _) in enumerate(block)
+                for s in spans]
+        n_q = _pow2(len(block))
+        row_terms = np.full((_pow2(len(rows)), max(len(s) for _, s in rows)),
+                            -1, np.int32)
+        row_len = np.zeros(len(row_terms), np.int32)
+        row_anchor = np.zeros(len(row_terms), np.int32)
+        row_query = np.zeros(len(row_terms), np.int32)
+        query_rows = np.full((n_q, max(len(q[1]) for q in block)), -1,
+                             np.int32)
+        query_terms = np.full((n_q, _pow2(max(len(q[2]) for q in block))),
+                              -1, np.int32)
+        anchors = 0
+        for r, (qi, span) in enumerate(rows):
+            cfs = self._pos_cf[span]
+            row_terms[r, : len(span)] = span
+            row_len[r] = len(span)
+            row_anchor[r] = int(np.argmin(cfs))
+            row_query[r] = qi
+            query_rows[qi, list(query_rows[qi]).index(-1)] = r
+            anchors += int(cfs.min())
+        for qi, (_, _, terms) in enumerate(block):
+            query_terms[qi, : len(terms)] = terms
+        n_chunks = chunk_bucket(sum(self._anchor_chunks(s)
+                                    for _, s in rows))
+        # rows, queries and widths are pow2-bucketed, n_chunks and cap
+        # chunk-bucketed; phrase queries never ride the coalescer's
+        # precompiled ladder (they route solo)
+        with obs_trace("phrase.match", rows=len(rows), n_chunks=n_chunks):
+            # lint: shape-universe-ok (bucketed shapes, see above)
+            cand_row, cand_doc, row_count, n_docs, slices = phrase_match(
+                self.positions, row_terms, row_len, row_anchor,
+                n_chunks=n_chunks, slop=slop)
+            with obs_trace("dispatch.device", blocks=1):
+                counts, n_docs, slices = jax.device_get(
+                    (row_count, n_docs, slices))
+            cap = min(chunk_bucket(int(counts.sum())),
+                      n_chunks * PHRASE_CHUNK)
+        with obs_trace("kernel", layout="phrase", scoring=scoring,
+                       rows=len(block)):
+            # lint: shape-universe-ok (bucketed shapes, see above)
+            out = phrase_topk(
+                self.positions, cand_row, cand_doc, row_count, row_query,
+                query_rows, query_terms, self.df, self.doc_len,
+                jnp.int32(self.meta.num_docs), cap=cap,
+                n_slices=chunk_bucket(int(slices)),
+                width=_pow2(counts[query_rows[:len(block), 0]].max(), 16),
+                k=k, scoring=scoring, k1=_k1, b=_b,
+                compat_int_idf=self.compat_int_idf)
+        reg = get_registry()
+        reg.incr("phrase.queries", len(block))
+        reg.incr("phrase.positions", anchors)
+        reg.incr("phrase.slots", n_chunks * PHRASE_CHUNK)
+        reg.incr("phrase.candidates", int(n_docs))
+        return out
 
     def _querylog_phrase(self, text, res, t0, *, k, scoring, rerank):
         """Query-log entry for one host-scored phrase query (slim form:
